@@ -7,39 +7,13 @@ import statistics
 import time
 from typing import Callable, Dict, List, Sequence
 
+from repro.compile_cache import enable_compile_cache
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
-def enable_persistent_compilation_cache() -> bool:
-    """Point XLA's persistent compilation cache at
-    ``$JAX_COMPILATION_CACHE_DIR`` when the env var is set (CI persists
-    the directory via actions/cache keyed on the jax pin, so the fused
-    mapper+executor's ~5-10 s per (calib, op-bucket) compiles are paid
-    once per pin bump, not once per run).  No-op without the env var or
-    on jax versions lacking a config knob; returns True when active.
-    Mirrored by tests/conftest.py for the pytest jobs."""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return False
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:
-        return False
-    # cache even sub-second compiles: the sweep's cost is many medium
-    # compiles, not one giant one (knobs exist on the pinned jax range;
-    # tolerate their absence on other versions)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    return True
-
-
-_COMPILATION_CACHE_ACTIVE = enable_persistent_compilation_cache()
+enable_compile_cache()
 
 
 def save_repo_json(filename: str, payload) -> str:

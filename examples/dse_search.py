@@ -27,6 +27,7 @@ from repro.core.dse.engine import EvalEngine
 from repro.core.dse.ga import GAConfig, run_ga
 from repro.core.dse.pareto import pareto_front
 from repro.core.dse.sweep import run_sweep
+from repro.compile_cache import enable_compile_cache
 
 
 def run_pipeline_demo(args):
@@ -142,4 +143,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -36,6 +36,7 @@ from repro.core.dse.ga import GAConfig, run_ga
 from repro.core.dse.store import MemoryLRUStore, SqliteStore, TieredStore
 from repro.core.dse.sweep import run_sweep
 from repro.serve.dse_service import DSEClient, DSEService
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -180,4 +181,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ from repro.core import compile_workload, hetero_bls, homogeneous_baseline, simul
 from repro.core.tpu_dse import search_mesh
 from repro.core.workloads.extract import extract_model
 from repro.models import get_config
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -50,4 +51,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

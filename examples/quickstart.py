@@ -10,6 +10,7 @@ import sys
 from repro.core import (compile_workload, hetero_bls, homogeneous_baseline,
                         simulate)
 from repro.core.workloads import build, workload_names
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -32,4 +33,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,7 @@ compose — every backend models both modes.
 import argparse
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def run_serving_demo(args):
@@ -113,4 +114,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ from repro.models import get_config
 from repro.optim.adamw import AdamWConfig
 from repro.train.fault import FaultInjector
 from repro.train.loop import TrainLoopConfig, train_loop
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -40,4 +41,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

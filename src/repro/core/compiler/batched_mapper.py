@@ -70,10 +70,10 @@ from ..calibrate.asap7 import CalibrationTable, DEFAULT_CALIB
 from ..ir import MAX_PREDS, OpClass
 from ..simulator.batched import (CHIP_KEYS, SCHEDULE_MODES, TILE_KEYS,
                                  _build_plan_exec, _OP_TABLE_KEYS,
-                                 fifo_insert)
+                                 fifo_insert, tile_sum)
 from ..simulator.costs import (ACT_CACHE_SLOTS, FIDELITIES,
                                MAX_DRAM_CHANNELS, MAX_LINKS, OP_COST_KEYS,
-                               cost_model, dram_channel_one_hot,
+                               cost_model, div_exact, dram_channel_one_hot,
                                noc_transfer_energy_pj, noc_transfer_seconds,
                                pipeline_bounds, split_op_fields,
                                steady_state_energy, xy_route_link_mask)
@@ -284,29 +284,30 @@ def _device_xs(ws: Dict[str, np.ndarray]) -> Tuple[dict, int]:
 # Device staging of prepared-workload op tables, cached by identity: the
 # search loop dispatches the same handful of ``prepared_workload`` dicts
 # every generation, and re-uploading ~30 (max_ops,) arrays per dispatch
-# is measurable host overhead.  Holding the ws reference in the value
-# pins the id, so a dead dict can never alias a cached entry; the caches
-# are FIFO-bounded (dropping an entry releases the pin with it).
-_XS_CACHE: Dict[int, tuple] = {}
-_SEARCH_XS_CACHE: Dict[int, tuple] = {}
+# is measurable host overhead.  Holding the ws references in the value
+# pins their ids, so a dead dict can never alias a cached entry; the
+# caches are FIFO-bounded (dropping an entry releases the pins with it).
+_XS_CACHE: Dict[tuple, tuple] = {}
+_SEARCH_XS_CACHE: Dict[tuple, tuple] = {}
 _XS_CACHE_MAX = 64
 
 
-def _staged(cache: Dict[int, tuple], ws: Dict[str, np.ndarray],
-            stage) -> tuple:
-    """Identity-pinned FIFO memo shared by the two staging caches."""
-    hit = cache.get(id(ws))
-    if hit is not None and hit[0] is ws:
+def _staged(cache: Dict[tuple, tuple], wss: tuple, stage) -> tuple:
+    """Identity-pinned FIFO memo of ``stage(wss)`` for a tuple of
+    prepared-workload dicts, shared by the two staging caches."""
+    key = tuple(map(id, wss))
+    hit = cache.get(key)
+    if hit is not None and all(a is b for a, b in zip(hit[0], wss)):
         return hit[1:]
-    out = stage(ws)
+    out = stage(wss)
     while len(cache) >= _XS_CACHE_MAX:
         cache.pop(next(iter(cache)))
-    cache[id(ws)] = (ws,) + out
+    cache[key] = (wss,) + out
     return out
 
 
 def _device_xs_cached(ws: Dict[str, np.ndarray]) -> Tuple[dict, int]:
-    return _staged(_XS_CACHE, ws, _device_xs)
+    return _staged(_XS_CACHE, (ws,), lambda wss: _device_xs(wss[0]))
 
 
 def place_configs(cfgs, sharding=None):
@@ -401,9 +402,9 @@ def map_and_simulate(ws: Dict[str, np.ndarray],
 # the search kernel: single-scan fused map+execute, class-specialized
 # =============================================================================
 
-def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
+def _build_search(calib: CalibrationTable, n_state: int,
                   enable_split: bool = True, fidelity: str = "aggregate"):
-    """ONE ``lax.scan`` over the op axis that maps *and* executes each op
+    """ONE loop over the op axis that maps *and* executes each op
     in the same step, with the cost model specialized per operator class.
 
     The class predicates (``op_cls``, ``splittable``, ``macs > 0``) come
@@ -425,12 +426,18 @@ def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
     (an inactive row never writes state, so dropping it is bitwise
     inert; compaction padding carries ``index == n_state`` and its
     state writes fall out via scatter ``mode="drop"``).
+
+    The op loop runs ``n_steps`` iterations, a traced scalar, over a
+    table padded to any longer length: one compiled body serves every
+    workload whose op count fits ``n_state``, so a program scoring W
+    workloads holds this kernel once, not W times (each copy costs tens
+    of seconds of TPU compile time).
     """
     cm = cost_model(calib, jnp)
     c = calib
     link = fidelity == "link"
 
-    def run(tile, chip, xs, total_macs):
+    def run(tile, chip, per_op, n_steps, total_macs):
         T = tile
         num_macs = T["num_macs"]
         n_tiles = jnp.sum(T["exists"])
@@ -743,7 +750,12 @@ def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
         if link:
             init = init + (jnp.zeros(MAX_LINKS, _F),
                            jnp.zeros(MAX_DRAM_CHANNELS, _F))
-        final, _ = jax.lax.scan(step, init, xs["per_op"])
+        def body(i, carry):
+            op = {k: jax.lax.dynamic_index_in_dim(v, i, keepdims=False)
+                  for k, v in per_op.items()}
+            return step(carry, op)[0]
+
+        final = jax.lax.fori_loop(0, n_steps, body, init)
         (_, _, _, ok, tile_finish, _, _, _, _, tile_ops, tile_active,
          e_mod, res_occ) = final[:13]
         link_occ, chan_occ = (final[13], final[14]) if link else (None, None)
@@ -755,7 +767,7 @@ def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
         leak_t = jnp.where(T["exists"] > 0,
                            c.leak_mw_per_mm2 * T["area_mm2"] * makespan
                            * resid * 1e9, 0.0)
-        leakage = jnp.sum(leak_t)
+        leakage = tile_sum(leak_t)
         energy = (e_mod["compute"] + e_mod["dram"] + e_mod["sram"]
                   + e_mod["irf"] + e_mod["orf"] + e_mod["dsp"]
                   + e_mod["special"] + e_mod["noc"] + leakage
@@ -764,9 +776,9 @@ def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
         out = {"latency_s": makespan, "energy_pj": energy,
                "achieved_tops": achieved, "ok": ok}
         dram_bytes, noc_busy = res_occ[0], res_occ[1]
-        leak_rate = jnp.sum(jnp.where(T["exists"] > 0,
-                                      c.leak_mw_per_mm2 * T["area_mm2"]
-                                      * resid * 1e9, 0.0))
+        leak_rate = tile_sum(jnp.where(T["exists"] > 0,
+                                       c.leak_mw_per_mm2 * T["area_mm2"]
+                                       * resid * 1e9, 0.0))
         out.update(pipeline_bounds(
             jnp, makespan, jnp.max(tile_active), dram_bytes,
             chip["dram_gbps"], noc_busy, chan_bytes=chan_occ,
@@ -779,64 +791,75 @@ def _build_search(calib: CalibrationTable, n_steps: int, n_state: int,
                                                   leak_rate, ii)
         out["achieved_tops_ss"] = jnp.where(ii > 0,
                                             total_macs / ii / 1e12, 0.0)
-        out["pipeline_depth"] = jnp.where(ii > 0, jnp.ceil(makespan / ii),
+        out["pipeline_depth"] = jnp.where(ii > 0, jnp.ceil(div_exact(jnp, makespan, ii)),
                                           1.0)
         return out
 
     return run
 
 
-def _search_xs_cached(ws: Dict[str, np.ndarray]):
-    """Compacted device staging for the search kernel (same identity-
-    pinned cache as ``_device_xs_cached``): (xs dict of compacted
-    (n_steps, ...) arrays, n_steps, n_state, total_macs)."""
-    return _staged(_SEARCH_XS_CACHE, ws, _search_xs)
+def _search_xs_cached(ws_list):
+    """Compacted device staging of a workload list for the search kernel
+    (same identity-pinned cache as ``_device_xs_cached``): (per_op dict
+    of (W, S, ...) arrays, (W,) int32 step counts, n_state, (W,)
+    total MACs)."""
+    return _staged(_SEARCH_XS_CACHE, tuple(ws_list), _search_xs)
 
 
-def _search_xs(ws: Dict[str, np.ndarray]):
-    n_state = len(ws["op_type"])
-    sel = np.flatnonzero((np.asarray(ws["valid"]) > 0)
-                         & (np.asarray(ws["fused"]) == 0))
-    # bucket the compacted scan length (multiples of 16) so near-size
-    # workloads share a jit trace; padding rows are valid=0 with
-    # index == n_state (their state writes are scatter-dropped)
-    n_steps = max(-(-len(sel) // 16) * 16, 16)
-    pad = n_steps - len(sel)
+def _search_xs(ws_list):
+    """Stack the W workloads' compacted op tables (active, unfused rows
+    only) into one (W, S, ...) table.  Workload w runs its first
+    ``steps[w]`` rows; the rest is padding it never reads.  ``n_state``
+    is the largest ORIGINAL op count, so each workload's ``preds`` and
+    ``index`` keep their original values; padding rows carry
+    ``index == n_state`` (scatter-dropped) and no predecessors."""
+    sels = [np.flatnonzero((np.asarray(ws["valid"]) > 0)
+                           & (np.asarray(ws["fused"]) == 0))
+            for ws in ws_list]
+    n_state = max(len(ws["op_type"]) for ws in ws_list)
+    # bucket the table length (multiples of 16): it is a data shape, so
+    # near-size workload sets share one compiled program
+    S = max(-(-max(len(sel) for sel in sels) // 16) * 16, 16)
+
+    def pad(a, fill):
+        out = np.full((S,) + a.shape[1:], fill, a.dtype)
+        out[:len(a)] = a
+        return out
+
     per_op = {}
     for k in _WS_KEYS:
-        a = np.asarray(ws[k], np.float64)[sel]
-        per_op[k] = jnp.asarray(np.concatenate(
-            [a, np.zeros(pad, np.float64)]))
-    preds = np.asarray(ws["preds"], np.int32)[sel]
-    per_op["preds"] = jnp.asarray(np.concatenate(
-        [preds, np.full((pad,) + preds.shape[1:], -1, np.int32)]))
-    per_op["index"] = jnp.asarray(np.concatenate(
-        [sel.astype(np.int32), np.full(pad, n_state, np.int32)]))
-    xs = {"per_op": per_op}
-    tm = jnp.asarray(float(ws["total_macs"]), _F)
-    return xs, n_steps, n_state, tm
+        per_op[k] = jnp.asarray(np.stack(
+            [pad(np.asarray(ws[k], np.float64)[sel], 0.0)
+             for ws, sel in zip(ws_list, sels)]))
+    per_op["preds"] = jnp.asarray(np.stack(
+        [pad(np.asarray(ws["preds"], np.int32)[sel], -1)
+         for ws, sel in zip(ws_list, sels)]))
+    per_op["index"] = jnp.asarray(np.stack(
+        [pad(sel.astype(np.int32), n_state) for sel in sels]))
+    steps = jnp.asarray([len(sel) for sel in sels], jnp.int32)
+    tms = jnp.asarray([float(ws["total_macs"]) for ws in ws_list], _F)
+    return per_op, steps, n_state, tms
 
 
 @functools.lru_cache(maxsize=64)
-def _jitted_search_population(calib: CalibrationTable,
-                              shapes: Tuple[Tuple[int, int], ...],
+def _jitted_search_population(calib: CalibrationTable, n_state: int,
                               enable_split: bool = True,
                               fidelity: str = "aggregate"):
     """One jitted dispatch evaluating a candidate batch on EVERY workload
-    of a generation: the per-workload single-scan search kernels run
-    back-to-back inside one executable, so a GA generation costs one
-    evaluation dispatch instead of W (no per-workload host sync, no
-    executable alternation between kernels)."""
-    fns = [_build_search(calib, n_steps, n_state, enable_split, fidelity)
-           for n_steps, n_state in shapes]
+    of a generation: the single-scan search kernel runs once per
+    workload (``lax.map`` over the stacked op tables of
+    ``_search_xs``), so a GA generation costs one evaluation dispatch
+    instead of W, and the program holds one copy of the kernel whatever
+    W is.  Returns a dict of (B, W) arrays."""
+    fn = _build_search(calib, n_state, enable_split, fidelity)
 
-    def run_all(tile, chip, xs_list, tm_list):
-        return [fn(tile, chip, xs, tm)
-                for fn, xs, tm in zip(fns, xs_list, tm_list)]
+    def run_all(tile, chip, per_op, steps, tms):
+        return jax.lax.map(lambda a: fn(tile, chip, *a),
+                           (per_op, steps, tms))
 
     batched = jax.vmap(run_all, in_axes=({k: 0 for k in TILE_KEYS},
                                          {k: 0 for k in CHIP_KEYS},
-                                         None, None))
+                                         None, None, None))
     return jax.jit(batched)
 
 
@@ -857,19 +880,16 @@ def search_population(ws_list, cfgs, calib: CalibrationTable = DEFAULT_CALIB,
             f"supported modes: {SCHEDULE_MODES}")
     if fidelity not in FIDELITIES:
         raise ValueError(f"fidelity must be one of {FIDELITIES}, got {fidelity!r}")
-    staged = [_search_xs_cached(ws) for ws in ws_list]
-    shapes = tuple((s[1], s[2]) for s in staged)
-    xs_list = tuple(s[0] for s in staged)
-    tm_list = tuple(s[3] for s in staged)
+    per_op, steps, n_state, tms = _search_xs_cached(ws_list)
     tile, chip = placed if placed is not None \
         else place_configs(cfgs, sharding)
-    fn = _jitted_search_population(calib, shapes, True, fidelity)
-    outs = fn(tile, chip, xs_list, tm_list)
+    fn = _jitted_search_population(calib, n_state, True, fidelity)
+    out = fn(tile, chip, per_op, steps, tms)
+    keys = out.keys() if out_keys is None else tuple(out_keys) + ("ok",)
+    host = {k: np.asarray(out[k]) for k in keys}
     results = []
-    for out in outs:
-        keys = out.keys() if out_keys is None \
-            else tuple(out_keys) + ("ok",)
-        res = {k: np.asarray(out[k]) for k in keys}
+    for j in range(len(ws_list)):
+        res = {k: np.ascontiguousarray(v[:, j]) for k, v in host.items()}
         res["area_mm2"] = cfgs["chip"]["chip_area"]
         res["peak_tops"] = cfgs["chip"]["peak_tops"]
         res["mode"] = mode

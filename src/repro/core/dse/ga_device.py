@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -308,10 +308,9 @@ def run_ga_device(sweep, bracket: float, cfg=None, seed: int = 0,
                               cfg.crossover_rate, cfg.mutation_rate)
     key = jax.random.PRNGKey(seed + int(bracket))
     sharding = None
-    if engine._sharding is not None \
-            and cfg.population % engine._sharding.mesh.size == 0:
+    if engine._sharding is not None:
         from ...launch.mesh import population_sharding
-        sharding = population_sharding()
+        sharding = population_sharding(cfg.population)
     pop_dev = jnp.asarray(pop, jnp.int32)
     if sharding is not None:
         pop_dev = jax.device_put(pop_dev, sharding)
@@ -353,7 +352,7 @@ def run_ga_device(sweep, bracket: float, cfg=None, seed: int = 0,
 
 _ARRAY_DIM_DEV = jnp.asarray(_ARRAY_DIM)
 _SRAM_KB_DEV = jnp.asarray(_SRAM_KB)
-_COUNT_DEV = jnp.asarray(_COUNT)
+_COUNT_DEV = jnp.asarray(_COUNT, jnp.int32)
 _ENGINE_DEV = jnp.asarray(_ENGINE)
 _SPARSITY_DEV = jnp.asarray(_SPARSITY)
 _DATAFLOW_DEV = jnp.asarray(_DATAFLOW)
@@ -361,13 +360,20 @@ _PIPE_DEV = jnp.asarray(_PIPE)
 _DB_DEV = jnp.asarray(_DB)
 _ASYM_DEV = jnp.asarray(_ASYM)
 _PREC_MASK_DEV = jnp.asarray(_PREC_MASK)
-_PREC_MAX_DEV = jnp.asarray(_PREC_MAX)
+_PREC_MAX_DEV = jnp.asarray(_PREC_MAX, jnp.int32)
 _DRAM_DEV = jnp.asarray(_DRAM)
 _HOPS_TABLE_DEV = jnp.asarray(_HOPS_TABLE)
 _TOPO_DEV = jnp.asarray(_TOPO)
-_ASPECT_DEV = jnp.asarray(_ASPECT)
 _NOC_BPC_DEV = jnp.asarray(_NOC_BPC)
 _DRAM_CH_DEV = jnp.asarray(_DRAM_CH)
+
+
+def _grid_table():
+    """(grid_w, grid_h) for every tile count 0..MAX_TILES and aspect
+    knob, computed by the host's ``grid_dims`` (as ``genomes_to_configs``
+    does) for the device builder to gather."""
+    n = np.arange(MAX_TILES + 1, dtype=np.float64)[:, None]
+    return tuple(jnp.asarray(t) for t in grid_dims(np, n, _ASPECT[None, :]))
 
 
 def _area_tables(calib: CalibrationTable):
@@ -449,7 +455,7 @@ def _chip_area_device(g, calib: CalibrationTable):
     (bitwise identical by construction).  Split out so the fused loop's
     all-hit generations (every child memoized) pay a handful of gathers
     instead of full config building.  Traceable inside jit."""
-    g = g.astype(jnp.int64)
+    g = g.astype(jnp.int32)      # int64 is emulated on the TPU
     B = g.shape[0]
     T = MAX_TILE_TYPES
 
@@ -471,7 +477,7 @@ def _chip_area_device(g, calib: CalibrationTable):
     counts = jnp.stack([_COUNT_DEV[tcol(t, "count") % 8] for t in range(T)],
                        axis=1)
     n_types = (g[:, 0] + 1)[:, None]
-    active = jnp.arange(T)[None, :] < n_types
+    active = jnp.arange(T, dtype=jnp.int32)[None, :] < n_types
     counts = jnp.where(active, counts, 0)
     num_tiles = counts.sum(axis=1)
 
@@ -481,7 +487,7 @@ def _chip_area_device(g, calib: CalibrationTable):
     area = jnp.zeros(B)
     for t in range(T):
         area = area + terms[:, t]
-    area = area + noc_tab[num_tiles.astype(jnp.int64),
+    area = area + noc_tab[num_tiles,
                           g[:, IDX_NOC_BPC] % 4, g[:, IDX_TOPO] % 2]
     return area + dram_tab[g[:, IDX_DRAM_CH] % 4]
 
@@ -495,7 +501,7 @@ def _configs_device(g, calib: CalibrationTable):
     Returns ``(tile, chip, chip_area)``: the search kernel's two config
     dicts (f64, exactly TILE_KEYS/CHIP_KEYS) plus the (P,) areas the
     fitness band needs.  Traceable inside jit."""
-    g = g.astype(jnp.int64)
+    g = g.astype(jnp.int32)      # int64 is emulated on the TPU
     B = g.shape[0]
     T = MAX_TILE_TYPES
 
@@ -560,13 +566,14 @@ def _configs_device(g, calib: CalibrationTable):
     counts = jnp.stack([_COUNT_DEV[tcol(t, "count") % 8] for t in range(T)],
                        axis=1)
     n_types = (g[:, 0] + 1)[:, None]
-    counts = jnp.where(jnp.arange(T)[None, :] < n_types, counts, 0)
+    counts = jnp.where(jnp.arange(T, dtype=jnp.int32)[None, :] < n_types,
+                       counts, 0)
 
     starts = jnp.concatenate(
         [jnp.zeros((B, 1), counts.dtype),
          jnp.cumsum(counts, axis=1)[:, :-1]], axis=1)
     ends = starts + counts
-    slots = jnp.arange(MAX_TILES)
+    slots = jnp.arange(MAX_TILES, dtype=jnp.int32)
     member = (slots[None, None, :] >= starts[:, :, None]) \
         & (slots[None, None, :] < ends[:, :, None])
 
@@ -579,8 +586,11 @@ def _configs_device(g, calib: CalibrationTable):
     tile["exists"] = member.any(axis=1).astype(jnp.float64)
 
     num_tiles = counts.sum(axis=1)
-    gw, gh = grid_dims(jnp, num_tiles.astype(jnp.float64),
-                       _ASPECT_DEV[g[:, IDX_ASPECT] % 3])
+    # grid dims as a gather too: round(sqrt(n) * aspect) meets exact .5
+    # ties (n = 9, aspect 0.5) that the TPU's float64 sqrt misses
+    grid = _grid_table()
+    gw = grid[0][num_tiles, g[:, IDX_ASPECT] % 3]
+    gh = grid[1][num_tiles, g[:, IDX_ASPECT] % 3]
     chip = {
         "dram_gbps": _DRAM_DEV[g[:, IDX_DRAM] % 6],
         "hops": _HOPS_TABLE_DEV[g[:, IDX_ICONN] % 4, num_tiles],
@@ -599,12 +609,12 @@ def _configs_device(g, calib: CalibrationTable):
     # PHY tables
     cnt_k = jnp.stack([tcol(t, "count") % len(_COUNT) for t in range(T)],
                       axis=1)
-    active = jnp.arange(T)[None, :] < n_types
+    active = jnp.arange(T, dtype=jnp.int32)[None, :] < n_types
     terms = jnp.where(active, count_tab[flat, cnt_k], 0.0)
     area = jnp.zeros(B)
     for t in range(T):
         area = area + terms[:, t]
-    area = area + noc_tab[num_tiles.astype(jnp.int64),
+    area = area + noc_tab[num_tiles,
                           g[:, IDX_NOC_BPC] % 4, g[:, IDX_TOPO] % 2]
     area = area + dram_tab[g[:, IDX_DRAM_CH] % 4]
     return tile, chip, area
@@ -629,8 +639,7 @@ class FusedRefinement:
 
 
 @functools.lru_cache(maxsize=16)
-def _refine_kernel(calib: CalibrationTable,
-                   shapes: Tuple[Tuple[int, int], ...], mode: str,
+def _refine_kernel(calib: CalibrationTable, n_state: int, mode: str,
                    population: int, islands: int, generations: int,
                    tournament: int, n_elite: int, crossover_rate: float,
                    mutation_rate: float, early_stop: int,
@@ -664,9 +673,9 @@ def _refine_kernel(calib: CalibrationTable,
     lkey, ekey, akey = _MODE_KEYS[mode]
     gen_fn = _genetics_kernel(Pi, tournament, n_elite, crossover_rate,
                               mutation_rate)
-    search_fn = _jitted_search_population(calib, shapes, True, fidelity)
+    search_fn = _jitted_search_population(calib, n_state, True, fidelity)
 
-    def score(pop, canon, memo, e_homo, lo, hi, alpha, xs_list, tm_list):
+    def score(pop, canon, memo, e_homo, lo, hi, alpha, ops, tms):
         # areas only (cheap gathers, bitwise _configs_device's) — full
         # config building happens inside the miss branch, so an all-hit
         # generation skips it along with the scan
@@ -678,11 +687,8 @@ def _refine_kernel(calib: CalibrationTable,
 
         def fresh(_):
             tile, chip, _ = _configs_device(pop, calib)
-            outs = search_fn(tile, chip, xs_list, tm_list)
-            l = jnp.stack([o[lkey] for o in outs], axis=1)     # (P, W)
-            e = jnp.stack([o[ekey] for o in outs], axis=1)
-            a = jnp.stack([o[akey] for o in outs], axis=1)
-            ok = jnp.stack([o["ok"] for o in outs], axis=1)
+            outs = search_fn(tile, chip, *ops, tms)
+            l, e, a, ok = (outs[k] for k in (lkey, ekey, akey, "ok"))  # (P, W)
             power = e * 1e-12 / jnp.maximum(l, 1e-30)
             t = a / jnp.maximum(power, 1e-30)
             # unmappable rows: inf latency/energy, zero TOPS/W (the
@@ -724,45 +730,57 @@ def _refine_kernel(calib: CalibrationTable,
         return (popI.at[ii, worst].set(mig_g),
                 fitI.at[ii, worst].set(mig_f))
 
-    def refine(pop0, key, memo, e_homo, lo, hi, alpha, xs_list, tm_list):
+    def breed(a):
+        gen, key, pop, fit, _ = a          # gen: generations completed
+        if I > 1:
+            popI = pop.reshape(I, Pi, L)
+            fitI = fit.reshape(I, Pi)
+            popI, fitI = jax.lax.cond(
+                (gen > 0) & (gen % migrate_every == 0),
+                lambda a: migrate(*a), lambda a: a, (popI, fitI))
+            pop = popI.reshape(P, L)
+            fit = fitI.reshape(P)
+        key, sub = jax.random.split(key)
+        if I == 1:
+            pop, canon = gen_fn(pop, fit, sub)
+        else:
+            subs = jax.random.split(sub, I)
+            popI, canonI = jax.vmap(gen_fn)(
+                pop.reshape(I, Pi, L), fit.reshape(I, Pi), subs)
+            pop = popI.reshape(P, L)
+            canon = canonI.reshape(P, L)
+        return key, pop, canon
+
+    def refine(pop0, key, memo, e_homo, lo, hi, alpha, ops, tms):
+        # Iteration 0 scores the seed population, every later one breeds
+        # and scores a generation: ``score`` (the search kernel) appears
+        # once in the program — each copy costs minutes of TPU compile.
         pop0 = pop0.astype(jnp.int32)
-        canon0 = _canonical_device(pop0)
-        fit, lat, en, tw, area, memo = score(
-            pop0, canon0, memo, e_homo, lo, hi, alpha, xs_list, tm_list)
-        gi = jnp.argmax(fit)
-        best = (fit[gi], pop0[gi], lat[gi], en[gi], tw[gi], area[gi])
-        hist = jnp.full(generations + 1, -jnp.inf).at[0].set(fit[gi])
-        carry = (jnp.asarray(0), jnp.asarray(0), key, pop0, fit,
-                 lat, en, tw, area, memo, best, hist)
+        W = e_homo.shape[0]
+        zw = jnp.zeros((P, W), jnp.float64)
+        best = (jnp.asarray(-jnp.inf, jnp.float64),
+                jnp.zeros(L, jnp.int32), jnp.zeros(W, jnp.float64),
+                jnp.zeros(W, jnp.float64), jnp.zeros(W, jnp.float64),
+                jnp.asarray(0.0, jnp.float64))
+        hist = jnp.full(generations + 1, -jnp.inf)
+        carry = (jnp.asarray(0), jnp.asarray(0), key, pop0,
+                 _canonical_device(pop0), jnp.zeros(P, jnp.float64),
+                 zw, zw, zw, jnp.zeros(P, jnp.float64), memo, best, hist)
 
         def cond(c):
-            gen, stall = c[0], c[1]
-            return (gen < generations) & (stall < early_stop)
+            it, stall = c[0], c[1]
+            return (it == 0) | ((it <= generations) & (stall < early_stop))
 
         def body(c):
-            (gen, stall, key, pop, fit, lat, en, tw, area, memo, best,
-             hist) = c
-            if I > 1:
-                popI = pop.reshape(I, Pi, L)
-                fitI = fit.reshape(I, Pi)
-                popI, fitI = jax.lax.cond(
-                    (gen > 0) & (gen % migrate_every == 0),
-                    lambda a: migrate(*a), lambda a: a, (popI, fitI))
-                pop = popI.reshape(P, L)
-                fit = fitI.reshape(P)
-            key, sub = jax.random.split(key)
-            if I == 1:
-                pop, canon = gen_fn(pop, fit, sub)
-            else:
-                subs = jax.random.split(sub, I)
-                popI, canonI = jax.vmap(gen_fn)(
-                    pop.reshape(I, Pi, L), fit.reshape(I, Pi), subs)
-                pop = popI.reshape(P, L)
-                canon = canonI.reshape(P, L)
+            (it, stall, key, pop, canon, fit, lat, en, tw, area, memo,
+             best, hist) = c
+            key, pop, canon = jax.lax.cond(
+                it > 0, breed, lambda a: (a[1], a[2], a[4]),
+                (it - 1, key, pop, fit, canon))
             fit, lat, en, tw, area, memo = score(
-                pop, canon, memo, e_homo, lo, hi, alpha, xs_list, tm_list)
+                pop, canon, memo, e_homo, lo, hi, alpha, ops, tms)
             gi = jnp.argmax(fit)
-            imp = fit[gi] > best[0]
+            imp = (it == 0) | (fit[gi] > best[0])
 
             def pick(new, old):
                 return jnp.where(imp, new, old)
@@ -771,12 +789,13 @@ def _refine_kernel(calib: CalibrationTable,
                     pick(lat[gi], best[2]), pick(en[gi], best[3]),
                     pick(tw[gi], best[4]), pick(area[gi], best[5]))
             stall = jnp.where(imp, 0, stall + 1)
-            hist = hist.at[gen + 1].set(best[0])
-            return (gen + 1, stall, key, pop, fit, lat, en, tw, area,
+            hist = hist.at[it].set(best[0])
+            return (it + 1, stall, key, pop, canon, fit, lat, en, tw, area,
                     memo, best, hist)
 
-        (gen, _, _, pop, fit, lat, en, tw, area, memo, best,
+        (it, _, _, pop, _, fit, lat, en, tw, area, memo, best,
          hist) = jax.lax.while_loop(cond, body, carry)
+        gen = it - 1
         return {"gen": gen, "pop": pop, "fit": fit, "lat": lat, "en": en,
                 "tw": tw, "area": area, "memo": memo, "hist": hist,
                 "best_fit": best[0], "best_genome": best[1],
@@ -872,13 +891,10 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
         raise ValueError(f"memo carries {memo.vals.shape[-1]}-workload "
                          f"rows; engine scores {W}")
 
-    staged = [_search_xs_cached(engine._prepared(w))
-              for w in engine.workloads]
-    shapes = tuple((s[1], s[2]) for s in staged)
-    xs_list = tuple(s[0] for s in staged)
-    tm_list = tuple(s[3] for s in staged)
+    per_op, steps, n_state, tms = _search_xs_cached(
+        [engine._prepared(w) for w in engine.workloads])
 
-    kernel = _refine_kernel(calib, shapes, engine.mode, P, islands,
+    kernel = _refine_kernel(calib, n_state, engine.mode, P, islands,
                             cfg.generations, cfg.tournament, n_elite,
                             cfg.crossover_rate, cfg.mutation_rate,
                             cfg.early_stop, int(migrate_every), mk,
@@ -889,10 +905,9 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
     if islands > 1:
         from ...launch.mesh import island_sharding
         sharding = island_sharding(islands)
-    elif engine._sharding is not None \
-            and P % engine._sharding.mesh.size == 0:
+    elif engine._sharding is not None:
         from ...launch.mesh import population_sharding
-        sharding = population_sharding()
+        sharding = population_sharding(P)
     if sharding is not None:
         pop_dev = jax.device_put(pop_dev, sharding)
 
@@ -900,7 +915,7 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
     out = kernel(pop_dev, key, memo,
                  jnp.asarray(e_homo), jnp.asarray(lo, jnp.float64),
                  jnp.asarray(hi, jnp.float64),
-                 jnp.asarray(cfg.alpha, jnp.float64), xs_list, tm_list)
+                 jnp.asarray(cfg.alpha, jnp.float64), (per_op, steps), tms)
 
     n_gens = int(out["gen"])
     history = [float(x) for x in np.asarray(out["hist"][:n_gens + 1])]

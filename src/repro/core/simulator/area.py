@@ -17,8 +17,19 @@ from ..calibrate.asap7 import CalibrationTable, DEFAULT_CALIB
 __all__ = ["tile_area", "chip_area", "area_breakdown", "noc_area_scale"]
 
 
+def _fsum_ltr(terms) -> float:
+    """Plain left-to-right float sum.  Python 3.12's ``sum()`` compensates
+    float rounding (Neumaier), which the vectorized area in
+    ``dse.engine.genomes_to_configs`` and the device kernels do not: this
+    keeps the scalar and batched areas bitwise equal."""
+    acc = 0.0
+    for t in terms:
+        acc = acc + t
+    return acc
+
+
 def tile_area(tile: TileTemplate, calib: CalibrationTable = DEFAULT_CALIB) -> float:
-    return sum(area_breakdown(tile, calib).values())
+    return _fsum_ltr(area_breakdown(tile, calib).values())
 
 
 def area_breakdown(tile: TileTemplate, calib: CalibrationTable = DEFAULT_CALIB) -> Dict[str, float]:
@@ -47,7 +58,7 @@ def noc_area_scale(noc_bytes_per_cycle: float, torus: bool) -> float:
 
 
 def chip_area(chip: ChipConfig, calib: CalibrationTable = DEFAULT_CALIB) -> float:
-    a = sum(tile_area(t, calib) * c for t, c in chip.tiles)
+    a = _fsum_ltr(tile_area(t, calib) * c for t, c in chip.tiles)
     a = a + chip.num_tiles * calib.a_noc_mm2_per_tile \
         * noc_area_scale(chip.noc_bytes_per_cycle, chip.torus)
     return a + (chip.dram_channels - 1) * calib.a_dram_phy_mm2

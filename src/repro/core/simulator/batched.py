@@ -41,7 +41,7 @@ from ..ir import MAX_PREDS, PlanTensor
 from .area import chip_area, tile_area
 from .costs import (ACT_CACHE_SLOTS, CACHE_FRAC, FIDELITIES,
                     MAX_DRAM_CHANNELS, MAX_LINKS, OP_COST_KEYS, cost_model,
-                    dram_channel_one_hot, grid_dims,
+                    div_exact, dram_channel_one_hot, grid_dims,
                     noc_transfer_energy_pj, noc_transfer_seconds,
                     pipeline_bounds, split_op_fields, steady_state_energy,
                     xy_route_link_mask)
@@ -155,6 +155,17 @@ def stack_plan_tables(tables: Sequence[PlanTensor]) -> Dict[str, np.ndarray]:
                                    np.float64)
     out["mode"] = modes.pop()
     return out
+
+
+def tile_sum(v):
+    """Sum over the (MAX_TILES,) tile lanes left to right from 0.0 — the
+    order ChipSim accumulates per-tile terms in.  ``jnp.sum`` leaves the
+    reduction order to XLA, which picks it per fusion, so the same sum
+    in two programs can differ in the last bit."""
+    acc = jnp.zeros((), v.dtype)
+    for t in range(v.shape[-1]):
+        acc = acc + v[..., t]
+    return acc
 
 
 # =============================================================================
@@ -427,7 +438,7 @@ def _build_plan_exec(calib: CalibrationTable, max_ops: int,
         leak_t = jnp.where(T["exists"] > 0,
                            c.leak_mw_per_mm2 * T["area_mm2"] * makespan
                            * resid * 1e9, 0.0)
-        leakage = jnp.sum(leak_t)
+        leakage = tile_sum(leak_t)
         energy = (e_mod["compute"] + e_mod["dram"] + e_mod["sram"]
                   + e_mod["irf"] + e_mod["orf"] + e_mod["dsp"]
                   + e_mod["special"] + e_mod["noc"] + leakage
@@ -446,9 +457,9 @@ def _build_plan_exec(calib: CalibrationTable, max_ops: int,
         # ---- throughput-mode steady state (§3.2): same composition as
         # ChipSim._steady_state, via the shared costs.pipeline_bounds ----
         dram_bytes, noc_busy = res_occ[0], res_occ[1]
-        leak_rate = jnp.sum(jnp.where(T["exists"] > 0,
-                                      c.leak_mw_per_mm2 * T["area_mm2"]
-                                      * resid * 1e9, 0.0))
+        leak_rate = tile_sum(jnp.where(T["exists"] > 0,
+                                       c.leak_mw_per_mm2 * T["area_mm2"]
+                                       * resid * 1e9, 0.0))
         out.update(pipeline_bounds(
             jnp, makespan, jnp.max(tile_active), dram_bytes,
             chip["dram_gbps"], noc_busy, chan_bytes=chan_occ,
@@ -461,7 +472,7 @@ def _build_plan_exec(calib: CalibrationTable, max_ops: int,
                                                   leak_rate, ii)
         out["achieved_tops_ss"] = jnp.where(ii > 0,
                                             total_macs / ii / 1e12, 0.0)
-        out["pipeline_depth"] = jnp.where(ii > 0, jnp.ceil(makespan / ii),
+        out["pipeline_depth"] = jnp.where(ii > 0, jnp.ceil(div_exact(jnp, makespan, ii)),
                                           1.0)
         return out
 

@@ -43,8 +43,27 @@ __all__ = [
     "CostModel", "cost_model", "ActivationCache",
     "noc_transfer_seconds", "noc_transfer_energy_pj", "split_op_fields",
     "grid_dims", "xy_route_link_mask", "dram_channel_one_hot",
-    "pipeline_bounds", "steady_state_energy",
+    "pipeline_bounds", "steady_state_energy", "div_exact",
 ]
+
+def div_exact(xp, a, b):
+    """``a / b``, exact whenever the quotient is an integer — what every
+    ``ceil``/``floor`` of a quotient below relies on (``floor_divide`` is
+    exact on every backend).  IEEE division
+    (math, numpy, XLA:CPU) is correctly rounded, so an integer quotient
+    comes out exact and this is ``a / b``.  The TPU emulates float64 with
+    float32 pairs and its division is not correctly rounded: 4096 / 64
+    may come out a hair off 64, and a ceil or floor of it moves a tile
+    count by one.  Where ``r * b == a`` for the integer ``r`` nearest the
+    quotient, the quotient is ``r`` and is returned as such.  (``r`` is
+    ``floor(q + 0.5)``: the TPU's float64 ``round`` is off by one above
+    2**22; its ``floor`` is exact.)"""
+    q = a / b
+    if xp.__name__ != "jax.numpy":      # the host oracle: left as it was
+        return q
+    r = xp.floor(q + 0.5)
+    return xp.where(r * b == a, r, q)
+
 
 # Version tag of the cost formulas below.  The persistent DSE result store
 # (``dse.store``) folds this into every content-addressed key, so bumping
@@ -136,7 +155,8 @@ OP_COST_KEYS = (
 def noc_transfer_seconds(xp, nbytes, noc_bpc, hops, base_cycles, ref_clock_hz):
     """Eq. 3 context: ceil(B / B_NoC) + hops * Delta_NoC cycles at the chip
     reference clock."""
-    return (xp.ceil(nbytes / noc_bpc) + hops * base_cycles) / ref_clock_hz
+    return ((xp.ceil(div_exact(xp, nbytes, noc_bpc)) + hops * base_cycles)
+            / ref_clock_hz)
 
 
 def noc_transfer_energy_pj(xp, nbytes, e_noc_pj_per_byte_hop, hops):
@@ -153,7 +173,7 @@ def grid_dims(xp, num_tiles, grid_aspect):
     partial.  Same float64 arithmetic on both backends."""
     n = xp.maximum(xp.asarray(num_tiles, getattr(xp, "float64")), 1.0)
     w = xp.clip(xp.round(xp.sqrt(n) * grid_aspect), 1.0, n)
-    return w, xp.ceil(n / w)
+    return w, xp.ceil(div_exact(xp, n, w))
 
 
 def xy_route_link_mask(xp, src, dst, grid_w, grid_h, torus):
@@ -228,19 +248,20 @@ def split_op_fields(xp, op, axis, kf):
     split) and the batched mapper (evaluating all three axes) so the
     slice arithmetic matches ``slice_op`` bitwise in every backend."""
     sub = {f: op[f] for f in OP_COST_KEYS}
-    sub_m = xp.where(axis == 1, xp.maximum(xp.floor(op["m"] / kf), 1.0),
-                     op["m"])
-    sub_n = xp.where(axis == 0, xp.maximum(xp.floor(op["n"] / kf), 1.0),
-                     op["n"])
-    sub_k = xp.where(axis == 2, xp.maximum(xp.floor(op["k"] / kf), 1.0),
-                     op["k"])
+
+    def part(v):
+        return xp.floor(div_exact(xp, v, kf))
+
+    sub_m = xp.where(axis == 1, xp.maximum(part(op["m"]), 1.0), op["m"])
+    sub_n = xp.where(axis == 0, xp.maximum(part(op["n"]), 1.0), op["n"])
+    sub_k = xp.where(axis == 2, xp.maximum(part(op["k"]), 1.0), op["k"])
     sub["m"], sub["n"], sub["k"] = sub_m, sub_n, sub_k
     sub["macs"] = xp.where(op["macs"] > 0, sub_m * sub_k * sub_n, op["macs"])
-    sub["bytes_in"] = xp.where(axis == 1, xp.floor(op["bytes_in"] / kf),
+    sub["bytes_in"] = xp.where(axis == 1, part(op["bytes_in"]),
                                op["bytes_in"])
-    sub["bytes_w"] = xp.where(axis != 1, xp.floor(op["bytes_w"] / kf),
+    sub["bytes_w"] = xp.where(axis != 1, part(op["bytes_w"]),
                               op["bytes_w"])
-    sub["bytes_out"] = xp.where(axis != 2, xp.floor(op["bytes_out"] / kf),
+    sub["bytes_out"] = xp.where(axis != 2, part(op["bytes_out"]),
                                 op["bytes_out"])
     return sub
 
@@ -343,6 +364,11 @@ class CostModel:
         self.dsp_ops_t = xp.asarray(DSP_OPS_TABLE, f64)
         self.sfu_need = xp.asarray(SFU_NEED, f64)
         self.bpe_t = xp.asarray(PRECISION_BYTES, f64)
+        # 2 ** precision as a gather: the TPU's float64 ``pow`` is not
+        # exact even at integer exponents, and the bit test below needs
+        # the exact power
+        self.prec_bit = xp.asarray(2.0 ** np.arange(len(PRECISION_BYTES)),
+                                   f64)
 
     # ---------------------------------------------------------------- helpers
     def _i32(self, v):
@@ -387,7 +413,8 @@ class CostModel:
         """Per-tile precision filter incl. asymmetric-MAC variants
         (TileTemplate.supports_precision)."""
         xp = self.xp
-        native = xp.floor_divide(T["prec_mask"], 2.0 ** prec) % 2 >= 1
+        native = xp.floor_divide(T["prec_mask"],
+                                 self.prec_bit[self._i32(prec)]) % 2 >= 1
         int8_ok = xp.floor_divide(T["prec_mask"], 2.0) % 2 >= 1
         fp16_ok = xp.floor_divide(T["prec_mask"], 4.0) % 2 >= 1
         asym48 = ((T["asym_mac"] == 1.0) | (T["asym_mac"] == 2.0)) \
@@ -432,9 +459,9 @@ class CostModel:
         """Engine-specific compute-cycle model (Eq. 4)."""
         xp = self.xp
         D = T["pipeline_depth"]
-        tn = xp.ceil(n / n_t)
-        tk = xp.ceil(k / xp.maximum(k_t, 1.0))
-        tm = xp.ceil(m / xp.maximum(m_t, 1.0))
+        tn = xp.ceil(div_exact(xp, n, n_t))
+        tk = xp.ceil(div_exact(xp, k, xp.maximum(k_t, 1.0)))
+        tm = xp.ceil(div_exact(xp, m, xp.maximum(m_t, 1.0)))
         m_eff = m / xp.maximum(tm, 1.0)
         k_eff = (k / xp.maximum(tk, 1.0)) / eta
         nm = xp.maximum(T["num_macs"], 1.0)
@@ -455,9 +482,9 @@ class CostModel:
         """Tiling-aware SRAM traffic (bytes in, weights, out, k-tiles) from
         dataflow reuse, including the AUTO rule (§3.2)."""
         xp = self.xp
-        tm = xp.ceil(m / xp.maximum(m_t, 1.0))
-        tk = xp.ceil(k / xp.maximum(k_t, 1.0))
-        tn = xp.ceil(n / xp.maximum(n_t, 1.0))
+        tm = xp.ceil(div_exact(xp, m, xp.maximum(m_t, 1.0)))
+        tk = xp.ceil(div_exact(xp, k, xp.maximum(k_t, 1.0)))
+        tn = xp.ceil(div_exact(xp, n, xp.maximum(n_t, 1.0)))
         auto_os = (m * n > 4.0 * k * n) & (m * n > 4.0 * m * k)
         df = xp.where(T["dataflow"] == int(Dataflow.AUTO),
                       xp.where(auto_os, float(Dataflow.OS),
@@ -485,8 +512,8 @@ class CostModel:
         is_scan = (op_type == int(OpType.SSM_SCAN)) & (seq_len > 1)
         per_step = (elems / xp.maximum(seq_len, 1.0)) * ops_pe
         cyc = xp.where(is_scan,
-                       seq_len * xp.ceil(per_step / lanes),
-                       xp.ceil(lane_ops / lanes))
+                       seq_len * xp.ceil(div_exact(xp, per_step, lanes)),
+                       xp.ceil(div_exact(xp, lane_ops, lanes)))
         ok = (T["dsp_count"] > 0) & (elems > 0)
         return xp.where(ok, cyc, 0.0), \
             xp.where(ok, lane_ops * self.c.e_dsp_pj_per_lane_op, 0.0)
@@ -499,10 +526,10 @@ class CostModel:
         n = xp.maximum(fft_n, 2.0)
         transforms = xp.maximum(elems / n, 1.0)
         lg = xp.log2(n)
-        c_fft = transforms * xp.ceil(n * lg / par)
+        c_fft = transforms * xp.ceil(div_exact(xp, n * lg, par))
         e_fft = transforms * (n / 2.0) * lg * c.e_fft_pj_per_butterfly
         t_ = xp.maximum(snn_t, 1.0)
-        c_lif = xp.ceil(elems / par) * t_
+        c_lif = xp.ceil(div_exact(xp, elems, par)) * t_
         e_lif = elems * t_ * c.e_lif_pj_per_neuron_step
         d = xp.maximum(poly_d, 1.0)
         c_pol = elems * d / par
@@ -529,15 +556,18 @@ class CostModel:
         tsteps = xp.maximum(op["snn_timesteps"], 1.0)
         lif_ops = op["elems"] * 4.0
         # divergence + membrane round-trips (§2.5): ~4x lane-efficiency loss
-        c_lif = tsteps * (xp.ceil(lif_ops / (lanes / 4.0))
-                          + xp.ceil(op["elems"] * 8.0 / T["sram_bpc"]))
+        c_lif = tsteps * (
+            xp.ceil(div_exact(xp, lif_ops, lanes / 4.0))
+            + xp.ceil(div_exact(xp, op["elems"] * 8.0, T["sram_bpc"])))
         e_lif = lif_ops * tsteps * c.e_dsp_pj_per_lane_op
         d = xp.maximum(op["poly_degree"], 1.0)
         pol_ops = op["elems"] * 2.0
-        c_pol = d * (xp.ceil(pol_ops / lanes)
-                     + xp.ceil(op["elems"] * 2.0 / T["sram_bpc"]))
+        c_pol = d * (
+            xp.ceil(div_exact(xp, pol_ops, lanes))
+            + xp.ceil(div_exact(xp, op["elems"] * 2.0, T["sram_bpc"])))
         e_pol = d * pol_ops * c.e_dsp_pj_per_lane_op
-        c_fft_dsp = xp.ceil(op["elems"] * 10.0 * xp.log2(n) / lanes)
+        c_fft_dsp = xp.ceil(div_exact(xp, op["elems"] * 10.0 * xp.log2(n),
+                                      lanes))
         e_fft_dsp = op["elems"] * 10.0 * xp.log2(n) * c.e_dsp_pj_per_lane_op
         is_fft = op["op_type"] == int(OpType.FFT)
         # The MAC lowering (and its DFT twiddle-weight SRAM surcharge)
@@ -573,7 +603,8 @@ class CostModel:
         c_mac = xp.where(
             (T["num_macs"] > 0) & self.supports_precision(T, op["precision"]),
             op["macs"] / xp.maximum(T["num_macs"] * eta, 1e-9),
-            xp.ceil(2.0 * op["macs"] / xp.maximum(T["dsp_lanes"], 1.0)))
+            xp.ceil(div_exact(xp, 2.0 * op["macs"],
+                              xp.maximum(T["dsp_lanes"], 1.0))))
         c_dsp, _ = self.dsp_cycles_energy(T, op["op_type"], op["elems"],
                                           op["seq_len"])
         c_sfu_nat, _ = self.sfu_cycles_energy(
@@ -617,23 +648,23 @@ class CostModel:
         in_b, w_b, out_b, tk = self.sram_traffic(
             T, op["m"], op["k"], op["n"], bpe, m_t, k_t, n_t)
         e_sram_mac = (in_b + w_b + out_b) * c.e_sram_pj_per_byte
-        irf_w = xp.ceil(in_b / 32.0) * 32.0
+        irf_w = xp.ceil(div_exact(xp, in_b, 32.0)) * 32.0
         irf_r = in_b * (1.0 - xp.minimum(op["act_sparsity"], 0.95))
         e_irf = (irf_w + irf_r) * c.e_irf_pj_per_byte
         orf_b = op["m"] * op["n"] * _ACC * (2.0 * tk - 1.0)
         e_orf = orf_b * c.e_orf_pj_per_byte
-        c_mem_mac = xp.ceil((in_b + w_b + out_b) / T["sram_bpc"])
+        c_mem_mac = xp.ceil(div_exact(xp, in_b + w_b + out_b, T["sram_bpc"]))
 
         # ---- DSP path ----------------------------------------------------
         c_dsp, e_dsp = self.dsp_cycles_energy(T, op["op_type"], op["elems"],
                                               op["seq_len"])
         stream_b = op["bytes_in"] + op["bytes_out"]
         e_sram_stream = stream_b * c.e_sram_pj_per_byte
-        c_mem_stream = xp.ceil(stream_b / T["sram_bpc"])
+        c_mem_stream = xp.ceil(div_exact(xp, stream_b, T["sram_bpc"]))
 
         # ---- MAC op lowered onto the DSP ---------------------------------
         lanes = xp.maximum(T["dsp_lanes"], 1.0)
-        c_mac_on_dsp = xp.ceil(2.0 * op["macs"] / lanes)
+        c_mac_on_dsp = xp.ceil(div_exact(xp, 2.0 * op["macs"], lanes))
         e_mac_on_dsp = 2.0 * op["macs"] * c.e_dsp_pj_per_lane_op
 
         # ---- SPECIAL path ------------------------------------------------
@@ -704,16 +735,18 @@ class CostModel:
         c_cmp, c_mem = st["c_cmp"], st["c_mem"]
 
         # ---- DRAM + ports + Eq. 5 combine --------------------------------
-        rd_al = xp.where(dram_rd > 0, xp.ceil(dram_rd / _BURST) * _BURST, 0.0)
-        wr_al = xp.where(dram_wr > 0, xp.ceil(dram_wr / _BURST) * _BURST, 0.0)
+        rd_al = xp.where(dram_rd > 0,
+                         xp.ceil(div_exact(xp, dram_rd, _BURST)) * _BURST, 0.0)
+        wr_al = xp.where(dram_wr > 0,
+                         xp.ceil(div_exact(xp, dram_wr, _BURST)) * _BURST, 0.0)
         total_dram = rd_al + wr_al
         bpc = bw_gbps * 1e9 / T["clock_hz"]
         c_dram = xp.where(total_dram > 0,
                           total_dram / xp.maximum(bpc, 1e-9)
                           + c.dram_latency_cycles, 0.0)
         e_dram = total_dram * c.e_dram_pj_per_byte
-        c_lp = xp.ceil(dram_rd / 64.0)
-        c_sp = xp.ceil(dram_wr / 64.0)
+        c_lp = xp.ceil(div_exact(xp, dram_rd, 64.0))
+        c_sp = xp.ceil(div_exact(xp, dram_wr, 64.0))
         c_tot = xp.where(T["double_buffer"] > 0,
                          xp.maximum(xp.maximum(c_cmp, c_mem), c_dram)
                          + c_lp + c_sp,
@@ -764,7 +797,7 @@ class CostModel:
         xp = self.xp
         stream_b = op["bytes_in"] + op["bytes_out"]
         e_sram_stream = stream_b * self.c.e_sram_pj_per_byte
-        c_mem_stream = xp.ceil(stream_b / T["sram_bpc"])
+        c_mem_stream = xp.ceil(div_exact(xp, stream_b, T["sram_bpc"]))
         return e_sram_stream, c_mem_stream
 
     def _bw_cycles(self, T, op, bw_gbps):
@@ -789,15 +822,15 @@ class CostModel:
         in_b, w_b, out_b, tk = self.sram_traffic(
             T, op["m"], op["k"], op["n"], bpe, m_t, k_t, n_t)
         e_sram_mac = (in_b + w_b + out_b) * c.e_sram_pj_per_byte
-        irf_w = xp.ceil(in_b / 32.0) * 32.0
+        irf_w = xp.ceil(div_exact(xp, in_b, 32.0)) * 32.0
         irf_r = in_b * (1.0 - xp.minimum(op["act_sparsity"], 0.95))
         e_irf = (irf_w + irf_r) * c.e_irf_pj_per_byte
         orf_b = op["m"] * op["n"] * _ACC * (2.0 * tk - 1.0)
         e_orf = orf_b * c.e_orf_pj_per_byte
-        c_mem_mac = xp.ceil((in_b + w_b + out_b) / T["sram_bpc"])
+        c_mem_mac = xp.ceil(div_exact(xp, in_b + w_b + out_b, T["sram_bpc"]))
         e_sram_stream, c_mem_stream = self._stream_static(T, op)
         lanes = xp.maximum(T["dsp_lanes"], 1.0)
-        c_mac_on_dsp = xp.ceil(2.0 * op["macs"] / lanes)
+        c_mac_on_dsp = xp.ceil(div_exact(xp, 2.0 * op["macs"], lanes))
         e_mac_on_dsp = 2.0 * op["macs"] * c.e_dsp_pj_per_lane_op
         on_mac = (T["num_macs"] > 0) \
             & self.supports_precision(T, op["precision"])
@@ -868,7 +901,8 @@ class CostModel:
         c_mac = xp.where(
             (T["num_macs"] > 0) & self.supports_precision(T, op["precision"]),
             op["macs"] / xp.maximum(T["num_macs"] * eta, 1e-9),
-            xp.ceil(2.0 * op["macs"] / xp.maximum(T["dsp_lanes"], 1.0)))
+            xp.ceil(div_exact(xp, 2.0 * op["macs"],
+                              xp.maximum(T["dsp_lanes"], 1.0))))
         return xp.maximum(c_mac, self._bw_cycles(T, op, bw_gbps))
 
     def roofline_cycles_dsp(self, T, op, bw_gbps):

@@ -10,8 +10,9 @@
                         polynomial SFU, §3.3.1).
 
 Each kernel ships with a pure-jnp oracle in ``ref.py`` and a jit'd
-dispatch wrapper in ``ops.py``; tests sweep shapes/dtypes in
-``interpret=True`` mode (this container is CPU-only — TPU is the target).
+dispatch wrapper in ``ops.py``; tests sweep shapes/dtypes calling the
+``*_pallas`` kernels with ``interpret=True`` (they run on the CPU; TPU is
+the target).
 """
 from .ops import dse_eval, flash_attention, ssm_scan, horner
 

@@ -35,6 +35,7 @@ from repro.models.model import set_activation_spec, set_scan_unroll
 from repro.optim.adamw import AdamWConfig
 from repro.serve.step import make_decode_step, make_prefill_step
 from repro.train.step import init_train_state, make_train_step
+from repro.compile_cache import enable_compile_cache
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
                 "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
@@ -334,4 +335,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ never-seen neighbours are simulated.
 import argparse
 import json
 import os
+from ..compile_cache import enable_compile_cache
 
 
 def dse_hillclimb(workloads, budget_mm2: float = 200.0, steps: int = 24,
@@ -161,4 +162,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -3,30 +3,25 @@
 A FUNCTION, not a module-level constant: importing this module never
 touches jax device state, so smoke tests see 1 CPU device while the
 dry-run (which sets --xla_force_host_platform_device_count=512 before any
-import) sees the full placeholder pod.
-
-``mesh_axis_kwargs`` is the JAX-version compat shim shared by every mesh
-construction site in the repo (train substrate, collective tests, and the
-DSE evaluation engine's candidate-axis sharding): ``jax.sharding.AxisType``
-only exists in newer JAX releases, and older ``jax.make_mesh`` rejects the
-``axis_types`` keyword outright, so on old versions we simply build the
-mesh without it (the default axis behaviour there is the same Auto mode).
+import) sees the full placeholder pod.  Every mesh is built by ``_mesh``
+with ``Auto`` axes (sharding propagates from the placed arrays).
 """
 from __future__ import annotations
 
+import logging
+
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh_for_devices",
-           "mesh_axis_kwargs", "candidate_sharding", "population_sharding",
+           "candidate_sharding", "population_sharding",
            "island_sharding", "default_islands"]
 
+log = logging.getLogger(__name__)
 
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """kwargs for ``jax.make_mesh``: ``axis_types`` when supported, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def candidate_sharding():
@@ -43,22 +38,27 @@ def candidate_sharding():
     devs = jax.devices()
     if len(devs) <= 1:
         return None
-    mesh = jax.make_mesh((len(devs),), ("candidates",),
-                         **mesh_axis_kwargs(1))
+    mesh = _mesh((len(devs),), ("candidates",))
     return jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("candidates"))
 
 
-def population_sharding():
+def population_sharding(population: int):
     """The GA generation loop's sharding: the (P, GENOME_LEN) population
     and its per-generation genetics dispatch
     (``core.dse.ga_device``) shard over the same ``"candidates"`` axis
     the evaluation batches use, so one mesh covers the whole
     search loop — selection/crossover/mutation on device AND the fused
-    exact scoring dispatches.  Same divisibility rule: the population
-    must be a mesh-size multiple or the device loop falls back to a
-    single-device placement (it checks before placing)."""
-    return candidate_sharding()
+    exact scoring dispatches.  Same divisibility rule: a population that
+    is not a mesh-size multiple runs on one device (logged), as does a
+    single-device host (``None``)."""
+    sharding = candidate_sharding()
+    if sharding is not None and int(population) % sharding.mesh.size:
+        log.warning("population_sharding: population %d does not divide "
+                    "over %d devices; the GA runs on one device",
+                    int(population), sharding.mesh.size)
+        return None
+    return sharding
 
 
 def island_sharding(n_islands: int):
@@ -74,9 +74,14 @@ def island_sharding(n_islands: int):
     caller falls back to single-device placement — same numbers, no
     collectives)."""
     devs = jax.devices()
-    if len(devs) <= 1 or int(n_islands) % len(devs) != 0:
+    if len(devs) <= 1:
         return None
-    mesh = jax.make_mesh((len(devs),), ("islands",), **mesh_axis_kwargs(1))
+    if int(n_islands) % len(devs) != 0:
+        log.warning("island_sharding: %d islands do not divide over %d "
+                    "devices; the island GA runs on one device",
+                    int(n_islands), len(devs))
+        return None
+    mesh = _mesh((len(devs),), ("islands",))
     return jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("islands"))
 
@@ -94,7 +99,7 @@ def default_islands(population: int) -> int:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return _mesh(shape, axes)
 
 
 def make_mesh_for_devices(n_devices: int, model_parallel: int = 1):
@@ -103,5 +108,5 @@ def make_mesh_for_devices(n_devices: int, model_parallel: int = 1):
     if n_devices % model_parallel:
         raise ValueError(f"{n_devices} devices not divisible by "
                          f"model_parallel={model_parallel}")
-    return jax.make_mesh((n_devices // model_parallel, model_parallel),
-                         ("data", "model"), **mesh_axis_kwargs(2))
+    return _mesh((n_devices // model_parallel, model_parallel),
+                 ("data", "model"))

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 from repro.models import get_config
 from repro.launch.shapes import SHAPES
+from repro.compile_cache import enable_compile_cache
 
 PEAK_FLOPS = 197e12      # bf16 / chip
 HBM_BW = 819e9           # B/s / chip
@@ -127,4 +128,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import time
 from repro.models import get_config
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainLoopConfig, train_loop
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

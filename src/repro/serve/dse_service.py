@@ -92,6 +92,7 @@ from ..core.dse.engine import (EngineStats, EvalEngine, canonical_genomes,
 from ..core.dse.pareto import pareto_mask
 from ..core.simulator.costs import COST_MODEL_VERSION
 from ..core.simulator.orchestrator import SCHEDULE_MODES
+from ..compile_cache import enable_compile_cache
 
 __all__ = ["DSEService", "DSEClient", "ServiceStats", "OverloadedError",
            "DeadlineExceededError"]
@@ -283,10 +284,9 @@ class DSEService:
         loop, thread = self._loop, self._thread
 
         async def _shutdown():
-            if self._server is not None:
-                self._server.close()
-                await self._server.wait_closed()
-                self._server = None
+            server, self._server = self._server, None
+            if server is not None:
+                server.close()      # stop accepting; peers stay open
             if drain:
                 deadline = loop.time() + timeout
                 while ((self._queue.qsize() or self._inflight)
@@ -312,6 +312,11 @@ class DSEService:
                 except Exception:   # noqa: BLE001 - already closed
                     pass
             self._conns.clear()
+            # only now: Server.wait_closed() waits for every open
+            # connection, so awaiting it before the aborts above would
+            # block stop() on a client that is merely idle
+            if server is not None:
+                await server.wait_closed()
 
         asyncio.run_coroutine_threadsafe(_shutdown(), loop).result()
         loop.call_soon_threadsafe(loop.stop)
@@ -1493,4 +1498,5 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

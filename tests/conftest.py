@@ -8,35 +8,25 @@ import pathlib
 import numpy as np
 import pytest
 
+from repro.compile_cache import enable_compile_cache
+
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
 
-# Persistent XLA compilation cache (mirrors benchmarks/common.py): when CI
-# sets JAX_COMPILATION_CACHE_DIR (persisted via actions/cache keyed on the
-# jax pin), the jitted simulator/mapper compiles are restored across runs
-# instead of re-paying ~5-10 s per (calib, op-bucket) pair.
-if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ["JAX_COMPILATION_CACHE_DIR"])
-        for _knob, _val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(_knob, _val)
-            except Exception:  # pragma: no cover - knob-less jax version
-                pass
-    except Exception:  # pragma: no cover - jax unavailable
-        pass
+# Persistent XLA compilation cache: $JAX_COMPILATION_CACHE_DIR or the
+# checkout's .jax_cache/ (repro.compile_cache), so the jitted
+# simulator/mapper compiles are paid once per cache, not once per run.
+enable_compile_cache()
 
 # Hypothesis example budgets: the default profile keeps tier-1 fast; the
 # CI "thorough" profile (non-blocking -m slow job) widens the search.
 try:
     from hypothesis import settings
 
-    settings.register_profile("default", deadline=None)
-    settings.register_profile("thorough", max_examples=300, deadline=None)
+    # database=None: no example database under .hypothesis/, so a run
+    # reads nothing beyond the committed tree
+    settings.register_profile("default", deadline=None, database=None)
+    settings.register_profile("thorough", max_examples=300, deadline=None,
+                              database=None)
     settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "default"))
 except ImportError:  # pragma: no cover - hypothesis is optional locally
     pass

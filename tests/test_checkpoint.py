@@ -182,7 +182,7 @@ def test_sigkill_resume_bitwise_equal(tmp_path):
     finally:
         if proc.poll() is None:        # pragma: no cover - cleanup
             proc.kill()
-            proc.wait()
+            proc.wait(timeout=60)
     assert stages == ["sweep", "refine"]
 
     # the records the child reported before dying are durable

@@ -192,6 +192,20 @@ def test_pad_size_rounds_to_mesh_multiple_after_bucket():
     assert plain._pad_size(17) == 20
 
 
+def test_reserve_shapes_pads_every_miss_count_minimally():
+    """``reserve_shapes`` registers the whole bucket range of a GA's miss
+    counts up front, so no count reuses a larger shape within the 1.5x
+    window: every batch pads to its own bucket."""
+    eng = EvalEngine(["kan"])
+    eng.reserve_shapes(200)
+    assert eng._shapes == set(range(16, 204, 4))
+    assert [eng._pad_size(n) for n in (1, 16, 17, 150, 200)] \
+        == [16, 16, 20, 152, 200]
+    fresh = EvalEngine(["kan"])
+    fresh._pad_size(200)
+    assert fresh._pad_size(150) == 200     # unreserved: reuses within 1.5x
+
+
 def test_memo_lru_eviction_bounded_and_correct():
     """The canonical-genome memo is a bounded LRU: size never exceeds
     ``memo_max``, the oldest (least recently touched) entries are evicted

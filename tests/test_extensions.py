@@ -57,8 +57,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.overlap import ring_allgather_matmul
-from repro.launch.mesh import mesh_axis_kwargs
-mesh = jax.make_mesh((8,), ("model",), **mesh_axis_kwargs(1))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).normal(size=(64, 32)), jnp.float32)
 w = jnp.asarray(np.random.default_rng(1).normal(size=(32, 48)), jnp.float32)
 with mesh:

@@ -73,7 +73,8 @@ def test_injector_thread_safety_counts_every_call():
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        t.join(timeout=60)
+        assert not t.is_alive()
     assert inj.calls()["store_get"] == 800
     assert inj.fired()["store_get"] == sum(hits)
 

@@ -189,12 +189,16 @@ def test_island_ga_determinism_under_shard():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
+from repro.core.dse.api import EngineConfig
 from repro.core.dse.engine import EvalEngine
 from repro.core.dse.ga import GAConfig
 from repro.core.dse.ga_device import run_ga_fused
-from repro.launch.mesh import island_sharding
+from repro.launch.mesh import island_sharding, population_sharding
 from repro.core.dse.sweep import run_sweep
 assert island_sharding(4) is not None
+# indivisible layouts fall back to one device (with a log line)
+assert island_sharding(3) is None and population_sharding(6) is None
+assert population_sharding(8) is not None
 sw = run_sweep(["kan"], samples_per_stratum=4, seed=0,
                brackets=(100.0, 200.0))
 cfg = GAConfig(population=16, generations=3, seed_top_k=8, early_stop=100)

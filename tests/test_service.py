@@ -74,7 +74,7 @@ def test_concurrent_tenants_share_dispatches(service):
 
     def tenant(i):
         try:
-            barrier.wait()
+            barrier.wait(timeout=60)
             out[i] = DSEClient(service=service).evaluate(g)
         except Exception as e:  # pragma: no cover - surfaced below
             errs.append(e)
@@ -83,7 +83,8 @@ def test_concurrent_tenants_share_dispatches(service):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=300)
+        assert not t.is_alive(), "tenant thread still waiting"
     assert not errs
     for k in METRICS:
         assert out[0][k].tobytes() == out[1][k].tobytes(), k
@@ -194,11 +195,11 @@ def test_deadline_bounds_the_wait_not_the_work():
     try:
         with pytest.raises(DeadlineExceededError):
             asyncio.run_coroutine_threadsafe(
-                svc.evaluate(g, deadline_s=1e-9), svc._loop).result()
+                svc.evaluate(g, deadline_s=1e-9), svc._loop).result(timeout=120)
         # the shared futures kept running: an unbounded follow-up gets
         # the full (bitwise-correct) answer
         out = asyncio.run_coroutine_threadsafe(
-            svc.evaluate(g), svc._loop).result()
+            svc.evaluate(g), svc._loop).result(timeout=300)
         local = EvalEngine(WLS).evaluate(g)
         for k in METRICS:
             assert local[k].tobytes() == out[k].tobytes(), k

@@ -100,7 +100,8 @@ def test_sqlite_concurrent_writers(tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=120)
+        assert not t.is_alive(), "store hammer thread still running"
     assert not errs
     assert len(stores[0]) == len(rows)        # first-write-wins, no dupes
     for k, r in rows.items():
