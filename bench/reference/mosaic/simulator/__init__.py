@@ -1,0 +1,1 @@
+"""Frozen copy; see ``bench/reference/__init__.py``."""
