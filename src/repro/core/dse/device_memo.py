@@ -57,6 +57,7 @@ jax.config.update("jax_enable_x64", True)  # rows are float64, like the store
 
 import jax.numpy as jnp
 
+from .. import telemetry
 from .encoding import GENOME_LEN
 
 __all__ = ["DeviceMemo", "PROBES", "memo_init", "memo_lookup",
@@ -213,6 +214,7 @@ def memo_to_arrays(memo: DeviceMemo) -> Tuple[np.ndarray, np.ndarray]:
     return keys, vals
 
 
+@telemetry.span("memo.preload")
 def memo_from_store(engine, capacity: int,
                     mode: Optional[str] = None) -> DeviceMemo:
     """Preload a fresh table from the engine store's in-memory tier (the
@@ -255,6 +257,7 @@ def clear_fresh(memo: DeviceMemo) -> DeviceMemo:
     return memo._replace(fresh=jnp.zeros_like(memo.fresh))
 
 
+@telemetry.span("memo.drain")
 def drain_to_store(memo: DeviceMemo, engine,
                    mode: Optional[str] = None) -> int:
     """Write every entry inserted since the last host sync into the

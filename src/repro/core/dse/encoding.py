@@ -21,6 +21,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..arch import (KNOB_GRID, MAX_TILE_TYPES, AsymMAC, ChipConfig, Dataflow,
                     Engine, Interconnect, Sparsity, TileTemplate)
 from ..ir import Precision
@@ -201,10 +202,14 @@ def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
     largest-area genome seen is accepted with area <= bracket, so the
     800 mm^2 homogeneous baseline is simply "the biggest homo chip" —
     consistent with the paper's iso-area comparison semantics.
+
+    Counts its ``area_fn`` calls as ``sweep.area_evals`` and the genomes
+    it returns as ``sweep.sampled`` (``repro.core.telemetry``).
     """
     lo, hi = bracket / 2.0, bracket
     bounds = _BOUNDS_CACHE
     out = []
+    area_evals = 0
     while len(out) < n:
         best_fallback, best_area = None, -1.0
         accepted = False
@@ -213,6 +218,7 @@ def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
             n_types = int(g[0]) + 1
             for _ in range(max_repair):
                 a = area_fn(g)
+                area_evals += 1
                 if lo < a <= hi:
                     out.append(g)
                     accepted = True
@@ -237,4 +243,6 @@ def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
             if best_fallback is None:
                 best_fallback = random_genomes(rng, 1, family=family)[0]
             out.append(best_fallback)
+    telemetry.count("sweep.area_evals", area_evals)
+    telemetry.count("sweep.sampled", n)
     return np.asarray(out[:n])

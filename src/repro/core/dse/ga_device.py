@@ -58,6 +58,7 @@ from ..calibrate.asap7 import CalibrationTable, DEFAULT_CALIB
 from ..simulator.batched import CHIP_KEYS, TILE_KEYS
 from ..simulator.costs import grid_dims
 from ..simulator.orchestrator import CACHE_FRAC
+from .. import telemetry
 from .api import EngineConfig
 from .device_memo import (DeviceMemo, drain_to_store, memo_from_store,
                           memo_init, memo_insert, memo_lookup)
@@ -636,6 +637,11 @@ class FusedRefinement:
     population: np.ndarray           # (P, GENOME_LEN) final genomes
     pop_metrics: Dict[str, np.ndarray]   # latency/energy/tops_w (P, W), area (P,)
     generations_run: int
+    # per scored generation (the seed population first): the rows the
+    # device memo answered, and 1 where the search scan ran — over all
+    # P rows, as it does whenever some row misses
+    memo_hits: np.ndarray            # (generations_run + 1,) int32
+    searched: np.ndarray             # (generations_run + 1,) int32
 
 
 @functools.lru_cache(maxsize=16)
@@ -716,7 +722,7 @@ def _refine_kernel(calib: CalibrationTable, n_state: int, mode: str,
         memo = memo_insert(memo, canon, jnp.stack([lat, en, tw], axis=1),
                            update=~hit)
         fit = _fitness_kernel(en, tw, lat, area, e_homo, lo, hi, alpha)
-        return fit, lat, en, tw, area, memo
+        return fit, lat, en, tw, area, memo, hit.sum(dtype=jnp.int32)
 
     def migrate(popI, fitI):
         order = jnp.argsort(-fitI, axis=1)             # best first
@@ -763,9 +769,11 @@ def _refine_kernel(calib: CalibrationTable, n_state: int, mode: str,
                 jnp.zeros(W, jnp.float64), jnp.zeros(W, jnp.float64),
                 jnp.asarray(0.0, jnp.float64))
         hist = jnp.full(generations + 1, -jnp.inf)
+        hits = jnp.zeros(generations + 1, jnp.int32)
         carry = (jnp.asarray(0), jnp.asarray(0), key, pop0,
                  _canonical_device(pop0), jnp.zeros(P, jnp.float64),
-                 zw, zw, zw, jnp.zeros(P, jnp.float64), memo, best, hist)
+                 zw, zw, zw, jnp.zeros(P, jnp.float64), memo, best, hist,
+                 hits)
 
         def cond(c):
             it, stall = c[0], c[1]
@@ -773,12 +781,13 @@ def _refine_kernel(calib: CalibrationTable, n_state: int, mode: str,
 
         def body(c):
             (it, stall, key, pop, canon, fit, lat, en, tw, area, memo,
-             best, hist) = c
+             best, hist, hits) = c
             key, pop, canon = jax.lax.cond(
                 it > 0, breed, lambda a: (a[1], a[2], a[4]),
                 (it - 1, key, pop, fit, canon))
-            fit, lat, en, tw, area, memo = score(
+            fit, lat, en, tw, area, memo, nhit = score(
                 pop, canon, memo, e_homo, lo, hi, alpha, ops, tms)
+            hits = hits.at[it].set(nhit)
             gi = jnp.argmax(fit)
             imp = (it == 0) | (fit[gi] > best[0])
 
@@ -791,13 +800,14 @@ def _refine_kernel(calib: CalibrationTable, n_state: int, mode: str,
             stall = jnp.where(imp, 0, stall + 1)
             hist = hist.at[it].set(best[0])
             return (it + 1, stall, key, pop, canon, fit, lat, en, tw, area,
-                    memo, best, hist)
+                    memo, best, hist, hits)
 
         (it, _, _, pop, _, fit, lat, en, tw, area, memo, best,
-         hist) = jax.lax.while_loop(cond, body, carry)
+         hist, hits) = jax.lax.while_loop(cond, body, carry)
         gen = it - 1
         return {"gen": gen, "pop": pop, "fit": fit, "lat": lat, "en": en,
                 "tw": tw, "area": area, "memo": memo, "hist": hist,
+                "memo_hits": hits,
                 "best_fit": best[0], "best_genome": best[1],
                 "best_lat": best[2], "best_en": best[3],
                 "best_tw": best[4], "best_area": best[5]}
@@ -831,7 +841,13 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
     pipeline passes ``memo=`` and manages boundaries itself).
 
     The engine's ``stats``/store see nothing per generation — that is
-    the point; hits/misses live in the device table until drained.
+    the point.  The kernel counts per generation the rows the device
+    memo answered and whether the search scan ran (it runs over all P
+    rows when any row misses; the rows that hit are then computed and
+    thrown away): ``FusedRefinement.memo_hits`` / ``searched``, summed
+    into the ``repro.core.telemetry`` counters ``refine.rows``,
+    ``refine.memo_hits``, ``refine.searched_rows`` and
+    ``refine.discarded_rows``.
     """
     from .ga import GAConfig, GAResult
     from ..compiler.batched_mapper import _search_xs_cached
@@ -919,6 +935,14 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
 
     n_gens = int(out["gen"])
     history = [float(x) for x in np.asarray(out["hist"][:n_gens + 1])]
+    memo_hits = np.asarray(out["memo_hits"][:n_gens + 1])
+    # the kernel skips the scan exactly when every row hits
+    searched = (memo_hits < P).astype(np.int32)
+    telemetry.count("refine.rows", P * (n_gens + 1))
+    telemetry.count("refine.memo_hits", int(memo_hits.sum()))
+    telemetry.count("refine.searched_rows", P * int(searched.sum()))
+    telemetry.count("refine.discarded_rows",
+                    int((memo_hits * searched).sum()))
     best_metrics = {"latency": np.asarray(out["best_lat"]),
                     "energy": np.asarray(out["best_en"]),
                     "tops_w": np.asarray(out["best_tw"]),
@@ -942,4 +966,4 @@ def run_ga_fused(sweep, bracket: float, cfg=None, seed: int = 0,
                      "energy": np.asarray(out["en"]),
                      "tops_w": np.asarray(out["tw"]),
                      "area": np.asarray(out["area"])},
-        generations_run=n_gens)
+        generations_run=n_gens, memo_hits=memo_hits, searched=searched)

@@ -19,6 +19,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import telemetry
 from ..calibrate.asap7 import CalibrationTable, DEFAULT_CALIB
 from ..workloads import build
 from .batch_eval import batch_evaluate, prepare_configs, prepare_workload
@@ -160,11 +161,13 @@ def run_sweep(workloads: Sequence[str], samples_per_stratum: int = 64,
         return float(engine.areas(genome[None, :])[0])
 
     genomes_all, fam_all = [], []
-    for fi, fam in enumerate(FAMILIES):
-        for b in brackets:
-            g = sample_in_bracket(rng, samples_per_stratum, fam, b, area_fn)
-            genomes_all.append(g)
-            fam_all.append(np.full(len(g), fi))
+    with telemetry.span("sweep.sample"):
+        for fi, fam in enumerate(FAMILIES):
+            for b in brackets:
+                g = sample_in_bracket(rng, samples_per_stratum, fam, b,
+                                      area_fn)
+                genomes_all.append(g)
+                fam_all.append(np.full(len(g), fi))
     genomes = np.concatenate(genomes_all)
     family = np.concatenate(fam_all)
 

@@ -152,6 +152,24 @@ def test_topology_gene_roundtrip():
         assert float(chip_f["chip_area"][i]) == chip_area(chip)
 
 
+def test_sample_in_bracket_counts_area_evals():
+    """``sweep.area_evals`` is the number of ``area_fn`` calls the
+    sampler made, ``sweep.sampled`` the genomes it returned."""
+    from repro.core import telemetry
+    calls = []
+
+    def area_fn(g):
+        calls.append(1)
+        return chip_area(decode(g))
+
+    before = telemetry.snapshot()
+    g = sample_in_bracket(np.random.default_rng(6), 12, "hetero_bls",
+                          200.0, area_fn)
+    c = telemetry.diff(telemetry.snapshot(), before)["counters"]
+    assert c["sweep.area_evals"] == len(calls) >= len(g)
+    assert c["sweep.sampled"] == len(g) == 12
+
+
 def test_homo_family_pins_interconnect_genes():
     """The §4.3 homogeneous baseline stays on the stock interconnect: its
     stratum pins the topology genes to the mesh/64B/1-channel defaults,

@@ -24,6 +24,7 @@ import sys
 import numpy as np
 import pytest
 
+from repro.core import telemetry
 from repro.core.dse.api import EngineConfig
 from repro.core.dse.engine import EvalEngine, genome_areas
 from repro.core.dse.encoding import GENOME_LEN, random_genomes
@@ -77,6 +78,59 @@ def test_fused_warm_memo_is_bitwise_inert():
     assert np.array_equal(cold.population, warm.population)
     for k in cold.pop_metrics:
         assert np.array_equal(cold.pop_metrics[k], warm.pop_metrics[k])
+
+
+def _refine_counters(before):
+    c = telemetry.diff(telemetry.snapshot(), before)["counters"]
+    return {k: c.get(k, 0) for k in ("refine.rows", "refine.memo_hits",
+                                     "refine.searched_rows",
+                                     "refine.discarded_rows")}
+
+
+def test_fused_warm_replay_counts_every_row_a_memo_hit():
+    """The replay of a synced cold run: the device memo answers every
+    row of every generation and the search scan never runs."""
+    sw = _sweep()
+    eng = _exact()
+    run_ga_fused(sw, 200.0, CFG, seed=2, engine=eng, islands=1)
+    before = telemetry.snapshot()
+    warm = run_ga_fused(sw, 200.0, CFG, seed=2, engine=eng, islands=1)
+    P = CFG.population
+    assert warm.memo_hits.shape == (warm.generations_run + 1,)
+    assert np.all(warm.memo_hits == P)
+    assert np.all(warm.searched == 0)
+    c = _refine_counters(before)
+    assert c["refine.memo_hits"] == c["refine.rows"]
+    assert c["refine.searched_rows"] == c["refine.discarded_rows"] == 0
+
+
+def test_fused_cold_run_counters_match_registry():
+    """With an empty memo the seed population is searched; the registry's
+    ``refine.*`` deltas are the sums of the per-generation arrays."""
+    sw = _sweep()
+    before = telemetry.snapshot()
+    cold = run_ga_fused(sw, 200.0, CFG, seed=2, engine=_exact(), islands=1,
+                        store_sync=False)
+    P, n = CFG.population, cold.generations_run
+    assert cold.searched[0] == 1 and cold.memo_hits[0] == 0
+    assert cold.memo_hits.sum() < P * (n + 1)
+    assert np.all((cold.memo_hits >= 0) & (cold.memo_hits <= P))
+    # the scan runs exactly when some row misses
+    assert np.array_equal(cold.searched, (cold.memo_hits < P).astype(int))
+    c = _refine_counters(before)
+    assert c["refine.rows"] == P * (n + 1) == cold.result.evaluated
+    assert c["refine.memo_hits"] == cold.memo_hits.sum()
+    assert c["refine.searched_rows"] == P * cold.searched.sum()
+    assert c["refine.discarded_rows"] == (cold.memo_hits
+                                          * cold.searched).sum()
+
+
+def test_fused_counters_seeded_determinism():
+    sw = _sweep()
+    runs = [run_ga_fused(sw, 200.0, CFG, seed=5, engine=_exact(), islands=1,
+                         store_sync=False) for _ in range(2)]
+    assert np.array_equal(runs[0].memo_hits, runs[1].memo_hits)
+    assert np.array_equal(runs[0].searched, runs[1].searched)
 
 
 def test_fused_frontend_validation():
