@@ -93,6 +93,10 @@ def genome_bounds() -> np.ndarray:
     return np.asarray(b, dtype=np.int32)
 
 
+_BOUNDS_CACHE = genome_bounds()
+_BOUNDS_CACHE.flags.writeable = False
+
+
 def _tile_slice(t: int) -> slice:
     start = 1 + t * FIELDS_PER_TILE
     return slice(start, start + FIELDS_PER_TILE)
@@ -177,8 +181,7 @@ def _family_fixup(genomes: np.ndarray, family: str) -> np.ndarray:
 def random_genomes(rng: np.random.Generator, n: int,
                    family: Optional[str] = None) -> np.ndarray:
     """Uniform random genomes, optionally constrained to a family stratum."""
-    bounds = genome_bounds()
-    g = (rng.random((n, GENOME_LEN)) * bounds).astype(np.int32)
+    g = (rng.random((n, GENOME_LEN)) * _BOUNDS_CACHE).astype(np.int32)
     if family is not None:
         g = _family_fixup(g, family)
     return g
@@ -187,15 +190,16 @@ def random_genomes(rng: np.random.Generator, n: int,
 _GROWABLE = tuple(_TILE_FIELDS.index(f) for f in ("count", "rows", "cols", "sram"))
 
 
-_BOUNDS_CACHE = genome_bounds()
-
-
 def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
                       bracket: float, area_fn, max_repair: int = 24,
                       max_attempts_per_sample: int = 12) -> np.ndarray:
     """Stratified sampling (paper §4.5): draw genomes and repair them into
     the (bracket/2, bracket] area band by growing/shrinking the structural
-    genes (tile count, array dims, SRAM).  ``area_fn(genome) -> mm^2``.
+    genes (tile count, array dims, SRAM).  ``area_fn(genome) -> mm^2``
+    is handed the genome as a list of Python ints, not as an int32 row:
+    the repair loop runs on the list, with the same draws in the same
+    order, and only the rows it returns are int32.  An ``area_fn`` that
+    indexes numpy-style (``g[None, :]``) converts the list first.
 
     Some strata are unreachable (a single-type Homo chip tops out near
     ~220 mm^2 on the paper's knob grid): after the attempt budget, the
@@ -207,20 +211,20 @@ def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
     it returns as ``sweep.sampled`` (``repro.core.telemetry``).
     """
     lo, hi = bracket / 2.0, bracket
-    bounds = _BOUNDS_CACHE
+    bounds = _BOUNDS_CACHE.tolist()
     out = []
     area_evals = 0
     while len(out) < n:
         best_fallback, best_area = None, -1.0
         accepted = False
         for _ in range(max_attempts_per_sample):
-            g = random_genomes(rng, 1, family=family)[0]
-            n_types = int(g[0]) + 1
+            g = random_genomes(rng, 1, family=family)[0].tolist()
+            n_types = g[0] + 1
             for _ in range(max_repair):
                 a = area_fn(g)
                 area_evals += 1
                 if lo < a <= hi:
-                    out.append(g)
+                    out.append(np.asarray(g, np.int32))
                     accepted = True
                     break
                 if a <= hi and a > best_area:
@@ -242,7 +246,7 @@ def sample_in_bracket(rng: np.random.Generator, n: int, family: str,
         if not accepted:
             if best_fallback is None:
                 best_fallback = random_genomes(rng, 1, family=family)[0]
-            out.append(best_fallback)
+            out.append(np.asarray(best_fallback, np.int32))
     telemetry.count("sweep.area_evals", area_evals)
     telemetry.count("sweep.sampled", n)
     return np.asarray(out[:n])

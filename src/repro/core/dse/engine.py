@@ -83,6 +83,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import operator
 import threading
 import time
 import warnings
@@ -105,8 +106,8 @@ from .store import MemoryLRUStore, ResultStore, TieredStore
 
 __all__ = ["EvalEngine", "EngineStats", "EngineConfig",
            "NonFiniteMetricsError", "genomes_to_configs", "genome_areas",
-           "canonical_genomes", "prepared_workload", "BACKENDS",
-           "SCHEDULE_MODES"]
+           "genome_area_fn", "canonical_genomes", "prepared_workload",
+           "BACKENDS", "SCHEDULE_MODES"]
 
 
 class NonFiniteMetricsError(RuntimeError):
@@ -357,6 +358,112 @@ def genome_areas(genomes: np.ndarray,
                  calib: CalibrationTable = DEFAULT_CALIB) -> np.ndarray:
     """(N,) chip areas straight from genomes (== chip_area(decode(g)))."""
     return genomes_to_configs(genomes, calib)["chip"]["chip_area"]
+
+
+@functools.lru_cache(maxsize=4)
+def _area_tables_host(calib: CalibrationTable):
+    """Host-precomputed Eq. 7 area tables over the full (discrete) knob
+    grid: per-type tile area, tile area x count, and NoC area by tile
+    count.  ``ga_device`` gathers from them on the device,
+    ``genome_area_fn`` on the host.  XLA:CPU contracts mul+add chains
+    into FMAs under jit — no flag or ``optimization_barrier`` prevents
+    it — which skips the host stack's per-product rounding and breaks
+    the device port's bitwise-parity contract.  So the device does NO
+    area arithmetic: every area value is a gather from these tables,
+    each entry computed by the exact numpy expressions
+    ``_per_type_values`` runs (identical rounding by construction).  Grid: prec(4) x engine(4) x sparsity(3)
+    x rows(5) x cols(5) x sfu(len _SFU) x sram(7) = 42 K entries."""
+    S = len(_SFU)
+    p_, e_, s_, r_, c_, f_, k_ = np.meshgrid(
+        np.arange(4), np.arange(4), np.arange(3), np.arange(5),
+        np.arange(5), np.arange(S), np.arange(7), indexing="ij")
+    sfu = _SFU[f_]
+    special = sfu > 0
+    rows = np.where(special, 0.0, _ARRAY_DIM[r_])
+    cols = np.where(special, 0.0, _ARRAY_DIM[c_])
+    num_macs = rows * cols
+    big = num_macs >= 1024.0
+    dsp_count = np.where(special, 1.0, np.where(big, 2.0, 1.0))
+    dsp_simd = np.full_like(dsp_count, 64.0)
+    max_prec = _PREC_MAX[p_]
+    eng_idx = np.asarray(_ENGINE[e_], np.int64)
+    sp_idx = np.asarray(_SPARSITY[s_], np.int64)
+    sram_kb = _SRAM_KB[k_]
+
+    a_mac_mm2 = np.asarray(calib.a_mac_mm2, np.float64)
+    eng_a = np.asarray(calib.engine_a_mult, np.float64)
+    sp_a = np.asarray(calib.sparsity_a_mult, np.float64)
+    a_mac_unit = a_mac_mm2[max_prec] * eng_a[eng_idx]
+    a_mac = num_macs * a_mac_unit * sp_a[sp_idx]
+    a_sram = sram_kb * calib.a_sram_mm2_per_kb
+    a_dsp = dsp_count * dsp_simd * calib.a_dsp_mm2_per_lane
+    sfu_i = np.asarray(sfu, np.int64)
+    a_spec = np.where(sfu_i & 1, calib.a_fft_mm2, 0.0)
+    a_spec = a_spec + np.where(sfu_i & 2, calib.a_lif_mm2, 0.0)
+    a_spec = a_spec + np.where(sfu_i & 4, calib.a_poly_mm2, 0.0)
+    a_ports = calib.a_ports_base_mm2 \
+        + (rows + cols) * calib.a_ports_per_lane_mm2
+    area = a_mac + a_sram + a_dsp + a_spec + a_ports
+
+    count_terms = area[..., None] * _COUNT        # x count, pre-rounded
+    max_tiles = MAX_TILE_TYPES * int(np.max(_COUNT))
+    # NoC term by (tile count, noc_bpc knob, torus knob): the host stack
+    # computes ``(num_tiles * a_noc) * noc_scale`` left-associatively —
+    # precompute every product here so the device gathers a finished
+    # float64 (the same FMA-contraction hazard as the tile terms)
+    n_tiles = np.arange(max_tiles + 1, dtype=np.float64)
+    noc_scale = (0.5 + 0.5 * _NOC_BPC / 64.0)[:, None] \
+        * np.where(_TOPO[None, :] > 0, 1.25, 1.0)
+    noc = (n_tiles * calib.a_noc_mm2_per_tile)[:, None, None] \
+        * noc_scale[None, :, :]
+    # per-channel DRAM PHY term by the dram_channels knob
+    dram_phy = (_DRAM_CH - 1.0) * calib.a_dram_phy_mm2
+    return (np.ascontiguousarray(area.reshape(-1)),
+            np.ascontiguousarray(count_terms.reshape(-1, len(_COUNT))),
+            np.ascontiguousarray(noc),
+            np.ascontiguousarray(dram_phy))
+
+
+# genes genome_area_fn reads per tile type, in its unpacking order
+_AREA_GENES = tuple(
+    operator.itemgetter(*(1 + t * FIELDS_PER_TILE + _FIELD_COL[f]
+                          for f in ("prec", "engine", "sparsity", "rows",
+                                    "cols", "sfu", "sram", "count")))
+    for t in range(MAX_TILE_TYPES))
+
+
+@functools.lru_cache(maxsize=4)
+def genome_area_fn(calib: CalibrationTable = DEFAULT_CALIB
+                   ) -> Callable[[Sequence[int]], float]:
+    """Scalar chip area of one genome: ``genome_area_fn(calib)(g) ==
+    chip_area(decode(g), calib)`` bitwise, for ``g`` any sequence of ints
+    (a Python list is fastest).  It follows ``ga_device._chip_area_device``
+    step for step over ``_area_tables_host``: each active type's count
+    term summed left to right from 0.0, then the NoC term, then the DRAM
+    PHY term.  The count terms are read through a read-only memoryview of
+    that table (42 K x 8 float64, ~2.7 MB per calibration, no copy);
+    only the small NoC and DRAM tables become Python lists.  The
+    stratified sampler scores every repair step with it, where ``decode``
+    + ``chip_area`` cost ~50 us a call."""
+    _, count_tab, noc_tab, dram_tab = _area_tables_host(calib)
+    n_sfu, n_cnt = len(_SFU), len(_COUNT)
+    count_terms = memoryview(count_tab.reshape(-1)).toreadonly()
+    counts = _COUNT.tolist()
+    noc_tab, dram_tab = noc_tab.tolist(), dram_tab.tolist()
+
+    def area(g: Sequence[int]) -> float:
+        acc = 0.0
+        num_tiles = 0
+        for genes in _AREA_GENES[:g[0] + 1]:
+            prec, eng, sp, rows, cols, sfu, sram, cnt = genes(g)
+            flat = (((((prec % 4 * 4 + eng % 4) * 3 + sp % 3) * 5 + rows % 5)
+                     * 5 + cols % 5) * n_sfu + sfu % n_sfu) * 7 + sram % 7
+            acc = acc + count_terms[flat * n_cnt + cnt % n_cnt]
+            num_tiles += counts[cnt % n_cnt]
+        acc = acc + noc_tab[num_tiles][g[IDX_NOC_BPC] % 4][g[IDX_TOPO] % 2]
+        return acc + dram_tab[g[IDX_DRAM_CH] % 4]
+
+    return area
 
 
 _SFU_COL = _FIELD_COL["sfu"]

@@ -70,7 +70,7 @@ from .engine import (_ARRAY_DIM, _ASPECT, _ASYM, _ASYM_CANON, _ASYM_COL,
                      _FIELD_COL, _HOPS_TABLE, _MODE_KEYS, _NOC_BPC, _PIPE,
                      _PREC_COL, _PREC_MASK, _PREC_MAX, _SFU, _SFU_COL,
                      _SPARSITY, _SPECIAL_INERT_COLS, _SRAM_KB, _TOPO,
-                     EvalEngine)
+                     EvalEngine, _area_tables_host)
 from .objective import ALPHA, AREA_BRACKETS, area_bracket
 
 __all__ = ["run_ga_device", "run_ga_fused", "FusedRefinement",
@@ -385,69 +385,6 @@ def _area_tables(calib: CalibrationTable):
     retrace (a second kernel shape in the same process) with an
     UnexpectedTracerError."""
     return tuple(jnp.asarray(t) for t in _area_tables_host(calib))
-
-
-@functools.lru_cache(maxsize=4)
-def _area_tables_host(calib: CalibrationTable):
-    """Host-precomputed Eq. 7 area tables over the full (discrete) knob
-    grid: per-type tile area, tile area x count, and NoC area by tile
-    count.  XLA:CPU contracts mul+add chains into FMAs under jit — no
-    flag or ``optimization_barrier`` prevents it — which skips the host
-    stack's per-product rounding and breaks this port's bitwise-parity
-    contract.  So the device does NO area arithmetic: every area value
-    is a gather from these tables, each entry computed by the exact
-    numpy expressions ``engine._per_type_values`` runs (identical
-    rounding by construction).  Grid: prec(4) x engine(4) x sparsity(3)
-    x rows(5) x cols(5) x sfu(len _SFU) x sram(7) = 42 K entries."""
-    S = len(_SFU)
-    p_, e_, s_, r_, c_, f_, k_ = np.meshgrid(
-        np.arange(4), np.arange(4), np.arange(3), np.arange(5),
-        np.arange(5), np.arange(S), np.arange(7), indexing="ij")
-    sfu = _SFU[f_]
-    special = sfu > 0
-    rows = np.where(special, 0.0, _ARRAY_DIM[r_])
-    cols = np.where(special, 0.0, _ARRAY_DIM[c_])
-    num_macs = rows * cols
-    big = num_macs >= 1024.0
-    dsp_count = np.where(special, 1.0, np.where(big, 2.0, 1.0))
-    dsp_simd = np.full_like(dsp_count, 64.0)
-    max_prec = _PREC_MAX[p_]
-    eng_idx = np.asarray(_ENGINE[e_], np.int64)
-    sp_idx = np.asarray(_SPARSITY[s_], np.int64)
-    sram_kb = _SRAM_KB[k_]
-
-    a_mac_mm2 = np.asarray(calib.a_mac_mm2, np.float64)
-    eng_a = np.asarray(calib.engine_a_mult, np.float64)
-    sp_a = np.asarray(calib.sparsity_a_mult, np.float64)
-    a_mac_unit = a_mac_mm2[max_prec] * eng_a[eng_idx]
-    a_mac = num_macs * a_mac_unit * sp_a[sp_idx]
-    a_sram = sram_kb * calib.a_sram_mm2_per_kb
-    a_dsp = dsp_count * dsp_simd * calib.a_dsp_mm2_per_lane
-    sfu_i = np.asarray(sfu, np.int64)
-    a_spec = np.where(sfu_i & 1, calib.a_fft_mm2, 0.0)
-    a_spec = a_spec + np.where(sfu_i & 2, calib.a_lif_mm2, 0.0)
-    a_spec = a_spec + np.where(sfu_i & 4, calib.a_poly_mm2, 0.0)
-    a_ports = calib.a_ports_base_mm2 \
-        + (rows + cols) * calib.a_ports_per_lane_mm2
-    area = a_mac + a_sram + a_dsp + a_spec + a_ports
-
-    count_terms = area[..., None] * _COUNT        # x count, pre-rounded
-    max_tiles = MAX_TILE_TYPES * int(np.max(_COUNT))
-    # NoC term by (tile count, noc_bpc knob, torus knob): the host stack
-    # computes ``(num_tiles * a_noc) * noc_scale`` left-associatively —
-    # precompute every product here so the device gathers a finished
-    # float64 (the same FMA-contraction hazard as the tile terms)
-    n_tiles = np.arange(max_tiles + 1, dtype=np.float64)
-    noc_scale = (0.5 + 0.5 * _NOC_BPC / 64.0)[:, None] \
-        * np.where(_TOPO[None, :] > 0, 1.25, 1.0)
-    noc = (n_tiles * calib.a_noc_mm2_per_tile)[:, None, None] \
-        * noc_scale[None, :, :]
-    # per-channel DRAM PHY term by the dram_channels knob
-    dram_phy = (_DRAM_CH - 1.0) * calib.a_dram_phy_mm2
-    return (np.ascontiguousarray(area.reshape(-1)),
-            np.ascontiguousarray(count_terms.reshape(-1, len(_COUNT))),
-            np.ascontiguousarray(noc),
-            np.ascontiguousarray(dram_phy))
 
 
 def _chip_area_device(g, calib: CalibrationTable):

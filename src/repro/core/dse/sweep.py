@@ -23,9 +23,9 @@ from .. import telemetry
 from ..calibrate.asap7 import CalibrationTable, DEFAULT_CALIB
 from ..workloads import build
 from .batch_eval import batch_evaluate, prepare_configs, prepare_workload
-from .encoding import FAMILIES, decode, random_genomes
+from .encoding import FAMILIES, decode, sample_in_bracket
 from .api import EngineConfig
-from .engine import EvalEngine
+from .engine import EvalEngine, genome_area_fn
 from .objective import ALPHA, AREA_BRACKETS, area_bracket
 
 __all__ = ["SweepResult", "run_sweep", "run_sweeps", "evaluate_genomes",
@@ -148,18 +148,18 @@ def run_sweep(workloads: Sequence[str], samples_per_stratum: int = 64,
     (``EvalEngine(backend="exact")``): every metric matrix — and hence
     the homogeneous baselines the GA's Eq. 8 fitness is measured
     against — holds exact fused-mapper numbers instead of the in-scan
-    approximate mapping's."""
-    from .encoding import sample_in_bracket
+    approximate mapping's.
 
+    The stratified sampler scores its repair steps with
+    ``genome_area_fn`` (the host Eq. 7 tables, bitwise ``chip_area``),
+    whatever the engine's ``vectorized`` setting: that setting chooses
+    how ``engine.evaluate`` stacks configs, not how strata are drawn."""
     engine = (engine.check_workloads(workloads, calib)
               if engine is not None
               else EvalEngine(workloads, calib, config=EngineConfig(
                   backend="exact" if exact else "scan")))
     rng = np.random.default_rng(seed)
-
-    def area_fn(genome):
-        return float(engine.areas(genome[None, :])[0])
-
+    area_fn = genome_area_fn(engine.calib)
     genomes_all, fam_all = [], []
     with telemetry.span("sweep.sample"):
         for fi, fam in enumerate(FAMILIES):

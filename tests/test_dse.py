@@ -170,6 +170,56 @@ def test_sample_in_bracket_counts_area_evals():
     assert c["sweep.sampled"] == len(g) == 12
 
 
+@pytest.mark.parametrize("seed,evals,digest", [
+    (1, 6697, "7087ecc55e332079"),
+    (2, 6996, "f83791380f35d0f9"),
+    (3, 6980, "75255cf12daa868d")])
+def test_sample_in_bracket_stream_pinned_by_area_gather(seed, evals, digest):
+    """The table gather drives the sampler through the very genomes the
+    reference ``decode`` + ``chip_area`` does: every stratum returns the
+    same int32 rows after the same number of area evaluations.  The
+    count and the rows' digest pin the stream itself, as the sampler
+    drew it when it repaired int32 rows with ``decode`` + ``chip_area``
+    on every step."""
+    import hashlib
+    from repro.core import telemetry
+    from repro.core.dse.engine import genome_area_fn
+
+    def sample(area_fn):
+        rng = np.random.default_rng(seed)
+        before = telemetry.snapshot()
+        out = [sample_in_bracket(rng, 8, fam, b, area_fn)
+               for fam in FAMILIES for b in AREA_BRACKETS]
+        c = telemetry.diff(telemetry.snapshot(), before)["counters"]
+        return out, c["sweep.area_evals"]
+
+    fast, fast_evals = sample(genome_area_fn())
+    ref, ref_evals = sample(lambda g: chip_area(decode(g)))
+    assert fast_evals == ref_evals == evals
+    for a, b in zip(fast, ref):
+        assert a.dtype == b.dtype == np.int32
+        assert np.array_equal(a, b)
+    rows = np.concatenate(fast)
+    assert rows.shape == (8 * len(FAMILIES) * len(AREA_BRACKETS), GENOME_LEN)
+    assert hashlib.sha256(rows.tobytes()).hexdigest()[:16] == digest
+
+
+def test_run_sweep_genomes_unchanged_by_area_gather(monkeypatch):
+    """``run_sweep`` samples the genomes the reference area path would."""
+    from repro.core.dse import sweep
+    from repro.core.dse.engine import EvalEngine
+    eng = EvalEngine(["kan"])
+    kw = dict(samples_per_stratum=2, seed=7, brackets=(100.0, 200.0),
+              engine=eng)
+    fast = run_sweep(["kan"], **kw)
+    monkeypatch.setattr(sweep, "genome_area_fn",
+                        lambda calib: lambda g: chip_area(decode(g), calib))
+    ref = run_sweep(["kan"], **kw)
+    assert fast.genomes.dtype == ref.genomes.dtype
+    assert np.array_equal(fast.genomes, ref.genomes)
+    assert np.array_equal(fast.area, ref.area)
+
+
 def test_homo_family_pins_interconnect_genes():
     """The §4.3 homogeneous baseline stays on the stock interconnect: its
     stratum pins the topology genes to the mesh/64B/1-channel defaults,

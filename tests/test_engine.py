@@ -2,6 +2,7 @@
 genome->SoA stacking against the reference decode() path, memoization
 identity, canonicalization soundness, prefilter semantics, and GA
 fixed-seed equivalence with the pre-refactor evaluation path."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -9,13 +10,18 @@ import sys
 import numpy as np
 import pytest
 
+from repro.core.arch import MAX_TILE_TYPES
+from repro.core.calibrate.asap7 import DEFAULT_CALIB
 from repro.core.dse.api import EngineConfig
 from repro.core.dse.batch_eval import (batch_evaluate, prepare_configs,
                                        prepare_workload)
-from repro.core.dse.encoding import (FIELDS_PER_TILE, GENOME_LEN,
-                                     _TILE_FIELDS, decode, random_genomes)
+from repro.core.dse.encoding import (FIELDS_PER_TILE, GENOME_LEN, IDX_ASPECT,
+                                     IDX_DRAM, IDX_DRAM_CH, IDX_ICONN,
+                                     IDX_NOC_BPC, IDX_TOPO, _TILE_FIELDS,
+                                     decode, genome_bounds, random_genomes)
 from repro.core.dse.engine import (EvalEngine, canonical_genomes,
-                                   genome_areas, genomes_to_configs)
+                                   genome_area_fn, genome_areas,
+                                   genomes_to_configs)
 from repro.core.dse.sweep import evaluate_genomes_reference
 from repro.core.workloads import build
 
@@ -45,6 +51,101 @@ def test_genome_areas_match_reference():
     areas = genome_areas(g)
     for i, x in enumerate(g):
         assert areas[i] == chip_area(decode(x))
+
+
+_PERTURBED_CALIB = dataclasses.replace(
+    DEFAULT_CALIB,
+    a_mac_mm2=tuple(a * 1.37 for a in DEFAULT_CALIB.a_mac_mm2),
+    engine_a_mult=(1.0, 1.21, 0.83, 0.71),
+    sparsity_a_mult=(1.0, 1.09, 1.03, 1.17, 1.01),
+    a_sram_mm2_per_kb=9.7e-4, a_dsp_mm2_per_lane=4.1e-4,
+    a_fft_mm2=0.061, a_lif_mm2=0.017, a_poly_mm2=0.029,
+    a_ports_base_mm2=0.41, a_ports_per_lane_mm2=1.1e-2,
+    a_noc_mm2_per_tile=0.052, a_dram_phy_mm2=2.3)
+
+
+def _area_edge_genomes():
+    """Random genomes of every family, then hand-set rows: every tile
+    gene at 0, at its grid's last index and at its bound (decode wraps
+    it) with 1, 2 and 3 active types, and each interconnect gene at
+    each of its values."""
+    bounds = genome_bounds()
+    rows = [_mixed_genomes(8, seed=21)]
+    tile = np.arange(1, 1 + MAX_TILE_TYPES * FIELDS_PER_TILE)
+    for n_types in range(MAX_TILE_TYPES):
+        for shift in (bounds, bounds - 1, np.zeros_like(bounds)):
+            g = np.zeros(GENOME_LEN, np.int64)
+            g[tile] = shift[tile]
+            g[0] = n_types
+            rows.append(g[None])
+    base = random_genomes(np.random.default_rng(22), 1, family="hetero_bls")
+    for idx in (IDX_DRAM, IDX_ICONN, IDX_TOPO, IDX_ASPECT, IDX_NOC_BPC,
+                IDX_DRAM_CH):
+        for v in range(bounds[idx]):
+            g = base.copy()
+            g[0, idx] = v
+            rows.append(g)
+    return np.concatenate(rows).astype(np.int32)
+
+
+@pytest.mark.parametrize("calib", [DEFAULT_CALIB, _PERTURBED_CALIB],
+                         ids=["default", "perturbed"])
+def test_genome_area_fn_bitwise_chip_area(calib):
+    """The scalar Eq. 7 table gather is ``chip_area(decode(g))`` exactly,
+    on int32 rows and on the Python-int lists the sampler hands it."""
+    from repro.core.simulator.area import chip_area
+    area = genome_area_fn(calib)
+    for g in _area_edge_genomes():
+        ref = chip_area(decode(g), calib)
+        assert area(g.tolist()) == ref, g.tolist()
+        assert area(g) == ref, g.tolist()
+
+
+@pytest.mark.parametrize("calib", [DEFAULT_CALIB, _PERTURBED_CALIB],
+                         ids=["default", "perturbed"])
+def test_engine_small_batch_areas_equal_genome_areas(calib):
+    """``EvalEngine.areas`` below 16 rows (``decode`` + ``chip_area``)
+    returns what ``genome_areas`` does, for every batch size it serves."""
+    g = _area_edge_genomes()
+    eng = EvalEngine(["kan"], calib)
+    for n in range(1, 16):
+        rows = g[n:2 * n]
+        out = eng.areas(rows)
+        assert out.dtype == np.float64 and out.shape == (n,)
+        assert out.tobytes() == genome_areas(rows, calib).tobytes(), n
+
+
+def test_reference_areas_go_through_decode(monkeypatch):
+    """``vectorized=False`` keeps ``decode`` + ``chip_area`` at every batch
+    size, the vectorized engine below 16 rows; the oracle rescore takes
+    its areas from that path, never from the Eq. 7 tables that the
+    device and the sweep's sampler gather from."""
+    import repro.core.dse.engine as engine_mod
+    from repro.core.simulator.area import chip_area
+    decoded = []
+
+    def counted(g, *a, **k):
+        decoded.append(1)
+        return decode(g, *a, **k)
+
+    def no_tables(*a, **k):
+        raise AssertionError("reference area read the Eq. 7 tables")
+
+    monkeypatch.setattr(engine_mod, "decode", counted)
+    g = _area_edge_genomes()[:20]
+    for n in (1, 15, 20):
+        decoded.clear()
+        out = EvalEngine(["kan"], config=EngineConfig(
+            vectorized=False)).areas(g[:n])
+        assert len(decoded) == n
+        assert out.tobytes() == genome_areas(g[:n]).tobytes()
+        decoded.clear()
+        EvalEngine(["kan"]).areas(g[:n])
+        assert len(decoded) == (n if n < 16 else 0)
+    monkeypatch.setattr(engine_mod, "_area_tables_host", no_tables)
+    monkeypatch.setattr(engine_mod, "genome_area_fn", no_tables)
+    ref = EvalEngine(["kan"]).rescore(g[:2], oracle=True)
+    assert ref["area"].tolist() == [chip_area(decode(x)) for x in g[:2]]
 
 
 def test_memoized_results_identical_to_fresh():
