@@ -3,8 +3,9 @@ the breakdown of the traced window.
 
 ``load_xplane`` reads the ``.xplane.pb`` that ``jax.profiler`` writes
 into a plain ``Trace``: per device, the op events and the module events;
-and the benchmark's own host spans (``TraceAnnotation`` names that start
-with ``bench:``).  ``reduce`` works on a ``Trace`` alone, so it is tested
+and the host spans of the benchmark (``TraceAnnotation`` names that
+start with ``bench:``) and of the program (``repro.core.telemetry``'s
+``mosaic:``).  ``reduce`` works on a ``Trace`` alone, so it is tested
 on a small recorded one (``bench/tests/data``).
 
 - The window is the ``bench:window`` span.  The TPU records every op,
@@ -21,8 +22,9 @@ on a small recorded one (``bench/tests/data``).
   ``(<id>)`` suffix dropped; its count is the number of executions.
   An execution that the end of a dropped trace cuts is left out.
 - The breakdown lists the ops that took the most device time, and the
-  idle time of the first device named by the innermost benchmark span
-  that covers the middle of each idle gap (``host`` where none does).
+  idle time of the first device named by the innermost host span, the
+  benchmark's or the program's, that covers the middle of each idle gap,
+  without its prefix (``host`` where none does).
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ Event = Tuple[str, float, float]        # name, start ns, duration ns
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DROPPED = "Trace Buffers Dropped"
-SPAN_PREFIX = "bench:"
+SPAN_PREFIXES = ("bench:", "mosaic:")
 WINDOW_SPAN = "bench:window"
 
 
@@ -48,7 +50,7 @@ WINDOW_SPAN = "bench:window"
 class Trace:
     ops: Dict[str, List[Event]]          # device plane -> op events
     modules: Dict[str, List[Event]]      # device plane -> module events
-    spans: List[Event]                   # the benchmark's host spans
+    spans: List[Event]                   # bench: and mosaic: host spans
     dropped: bool = False                # the device dropped trace buffers
 
     def to_json(self) -> dict:
@@ -91,7 +93,7 @@ def load_xplane(log_dir: str) -> Optional[Trace]:
             for line in plane.lines:
                 spans.extend((e.name, float(e.start_ns), float(e.duration_ns))
                              for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
+                             if e.name.startswith(SPAN_PREFIXES))
     return Trace(ops, mods, spans, dropped)
 
 
@@ -129,13 +131,14 @@ def _clip(events: List[Event], lo: float, hi: float):
 
 
 def _span_at(spans: List[Event], t: float) -> str:
-    """The innermost (shortest) benchmark span covering ``t``."""
+    """The innermost (shortest) host span covering ``t``, without its
+    prefix."""
     best = None
     for name, s, d in spans:
         if name != WINDOW_SPAN and s <= t <= s + d \
                 and (best is None or d < best[1]):
             best = (name, d)
-    return best[0][len(SPAN_PREFIX):] if best else "host"
+    return best[0].split(":", 1)[1] if best else "host"
 
 
 def reduce(trace: Trace, top: int = 10) -> Optional[dict]:

@@ -1,6 +1,8 @@
 """Score (genome, workload) pairs with the frozen numpy oracle.
 
-Per pair: decode the genome into a chip, build the workload graph, run
+Per pair: decode the genome into a chip, build the workload graph (one
+of the 20 frozen suite graphs, or the ``build()`` of
+``graphs/<name>.py`` beside this file for any other name), run
 precision assignment and fusion, map it with ``map_graph``, emit the
 schedule in the configuration's mode and walk it with ``ChipSim`` at the
 configuration's fidelity.  Latency mode reports (latency s, energy pJ,
@@ -11,6 +13,8 @@ from __future__ import annotations
 
 import copy
 import functools
+import importlib.util
+import os
 from typing import Sequence, Tuple
 
 import numpy as np
@@ -23,14 +27,44 @@ from .mosaic.compiler.schedule import emit_schedule
 from .mosaic.dse.encoding import decode
 from .mosaic.simulator.area import chip_area
 from .mosaic.simulator.orchestrator import simulate
-from .mosaic.workloads.suite import build
+from .mosaic.workloads.suite import SUITE_BUILDERS, build
 
 __all__ = ["score_pair", "score_rows", "area"]
+
+GRAPHS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "graphs")
+
+
+def _graph_file(name: str) -> str:
+    return os.path.join(GRAPHS, name + ".py")
+
+
+def _build_file(name: str):
+    """``build()`` of ``graphs/<name>.py``, loaded as a module of the
+    ``graphs`` package so that it imports the frozen builders relatively."""
+    spec = importlib.util.spec_from_file_location(
+        f"{__package__}.graphs.{name.replace('.', '_').replace('-', '_')}",
+        _graph_file(name))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    g = mod.build()
+    g.validate()
+    return g
 
 
 @functools.lru_cache(maxsize=64)
 def _graph(name: str):
-    g = copy.deepcopy(build(name))
+    """The fused graph of a suite name, or of ``graphs/<name>.py``."""
+    has_file = os.path.isfile(_graph_file(name))
+    if name in SUITE_BUILDERS:
+        if has_file:
+            raise ValueError(f"{_graph_file(name)} reuses the suite name "
+                             f"{name!r}")
+        g = copy.deepcopy(build(name))
+    elif has_file:
+        g = copy.deepcopy(_build_file(name))
+    else:
+        raise KeyError(f"unknown workload {name!r}: not a suite name "
+                       f"({sorted(SUITE_BUILDERS)}) and no {_graph_file(name)}")
     return fuse(assign_precision(g, aggressive_int4=False))
 
 

@@ -65,6 +65,16 @@ def devices_or_exit(chips: int):
     return devs[:chips]
 
 
+def timed_window(driver, seconds: float):
+    """``driver.window(seconds)``, and what the program's own spans and
+    counters (``repro.core.telemetry``) recorded inside it: the delta of
+    the registry across the window, so nothing of set-up."""
+    from repro.core import telemetry
+    before = telemetry.snapshot()
+    win = driver.window(seconds)
+    return win, telemetry.diff(telemetry.snapshot(), before)
+
+
 def run(args, cell, devices, out_dir: str) -> dict:
     """Set up, measure, check; returns the result line as a dict."""
     import jax
@@ -100,11 +110,11 @@ def run(args, cell, devices, out_dir: str) -> dict:
             jax.profiler.start_trace(log_dir, profiler_options=opts)
             try:
                 with jax.profiler.TraceAnnotation("bench:window"):
-                    win = driver.window(seconds)
+                    win, program = timed_window(driver, seconds)
             finally:
                 jax.profiler.stop_trace()
         else:
-            win = driver.window(args.seconds)
+            win, program = timed_window(driver, args.seconds)
         window_compiles = clock.read()[1] - compiles0
         peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                    for d in devices)
@@ -150,7 +160,7 @@ def run(args, cell, devices, out_dir: str) -> dict:
     metrics = {}
     if args.trace:
         ctx = {"trace": trace, "counters": win.counters,
-               "window_compiles": window_compiles}
+               "window_compiles": window_compiles, "program": program}
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"])(ctx)
             if v is not None:

@@ -1,6 +1,7 @@
 """The reduction from a trace to busy time, module time and the
-breakdown: on a hand-made trace with known answers, and on a small trace
-recorded on a TPU v5e (``data/``)."""
+breakdown: on a hand-made trace with known answers, on a small trace
+recorded on a TPU v5e (``data/``), and on a CPU trace for the host spans
+the reading keeps."""
 import glob
 import os
 
@@ -76,3 +77,47 @@ def test_recorded_sweep_slice():
     assert max(gaps, key=gaps.get) == "run_sweep"
     assert gaps["run_sweep"] > 2.0
     assert list(r["modules"]) == ["jit_run_all"]
+
+
+def test_program_span_names_the_gap():
+    """A program span (``mosaic:``) inside a benchmark span is the
+    innermost, and names the gap without its prefix."""
+    t = _made()
+    t.spans.append(("mosaic:sweep.sample", 30 * MS, 15 * MS))
+    r = trace.reduce(t)
+    gaps = dict(r["breakdown"]["idle_gaps"])
+    # [30, 50] middle 40 -> sweep.sample; [60, 95] middle 77.5 -> run_sweep
+    assert gaps == pytest.approx({"host": 0.01, "sweep.sample": 0.02,
+                                  "run_sweep": 0.035})
+    assert r["busy_s"] == pytest.approx(0.035)
+    assert r["window_s"] == pytest.approx(0.1)
+
+
+def test_recorded_sweep_reads_as_before():
+    """Busy, window and modules of the recorded slice, pinned to what
+    the reduction read before it kept the program's spans."""
+    r = trace.reduce(trace.load_json(os.path.join(HERE, "data",
+                                                  "sweep_v5e.trace.json")))
+    assert r["busy_s"] == 6.418595186
+    assert r["window_s"] == 8.987399721000001
+    assert r["modules"] == {"jit_run_all": [6.418615035, 1]}
+    assert r["dropped"] is False and r["devices"] == 1
+
+
+def test_load_keeps_benchmark_and_program_spans(tmp_path):
+    """On the CPU's profiler: ``bench:`` and ``mosaic:`` host spans are
+    kept, other host events are not."""
+    import jax
+    from repro.core import telemetry
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench:window"):
+            with telemetry.span("memo.preload"):
+                with jax.profiler.TraceAnnotation("other"):
+                    jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    t = trace.load_xplane(str(tmp_path))
+    names = {n for n, _, _ in t.spans}
+    assert {"bench:window", "mosaic:memo.preload"} <= names
+    assert all(n.startswith(("bench:", "mosaic:")) for n in names)

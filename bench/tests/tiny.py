@@ -1,7 +1,7 @@
 """Drive one cell end to end, skipping the look for a chip.
 
     python3 bench/tests/tiny.py <cell> [--seed N ...] [--seconds S]
-        [--full] [--control float32] [--fault KIND]
+        [--trace 0|1] [--full] [--control float32] [--fault KIND]
 
 Runs everything else a run does: set-up, the window, the reference and
 the checks; prints one result line per seed.  By default the cell keeps
@@ -108,6 +108,7 @@ def main(argv=None) -> int:
     ap.add_argument("cell")
     ap.add_argument("--seed", type=int, nargs="+", default=[2 ** 33 + 5])
     ap.add_argument("--seconds", type=float, default=0.5)
+    ap.add_argument("--trace", default="0", choices=("0", "1"))
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--control", default=None)
     ap.add_argument("--fault", choices=("alter", "half", "frozen"),
@@ -124,7 +125,7 @@ def main(argv=None) -> int:
         plant(a.fault, cell.traffic["kind"])
     for seed in a.seed:
         args = run.parse(["--workload", a.cell, "--seed", str(seed),
-                          "--seconds", str(a.seconds), "--trace", "0"]
+                          "--seconds", str(a.seconds), "--trace", a.trace]
                          + (["--control", a.control] if a.control else []))
         line = run.run(args, cell, jax.devices()[:1],
                        os.path.join(ROOT, ".bench_out", "tiny"))
